@@ -771,12 +771,6 @@ def run_sharded(args) -> dict:
     device->host transfers) and recording shard-count scaling curves to
     ``BENCH_sharded.json``.  The store comes from the *streamed* generator
     so ``--sf`` can exceed single-device generation sizes."""
-    # the faked mesh must exist before the first jax import
-    import os
-    if "jax" not in sys.modules:
-        os.environ.setdefault("XLA_FLAGS",
-                              "--xla_force_host_platform_device_count=8")
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import numpy as np
 
     from benchmarks import queries as Q
@@ -1053,6 +1047,13 @@ def main():
         for name, argv in CI_BENCHES:
             print(f"{name}\t{argv}")
         sys.exit(0)
+    if args.sharded and "jax" not in sys.modules:
+        # the faked CPU mesh must exist before the first jax import
+        import os
+        os.environ.setdefault("XLA_FLAGS",
+                              "--xla_force_host_platform_device_count=8")
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.sharded:
         args.out = args.out or "BENCH_sharded.json"
         out = run_sharded(args)

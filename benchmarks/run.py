@@ -27,6 +27,8 @@ def main() -> None:
     ap.add_argument("--out", default="benchmarks/results.json")
     args = ap.parse_args()
     tables = set(args.tables.split(","))
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     coll = T.Collector()
     results = {"sf": args.sf}

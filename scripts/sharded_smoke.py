@@ -19,7 +19,6 @@ import sys
 if "jax" not in sys.modules:
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=8")
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, "src")
 sys.path.insert(1, ".")

@@ -92,11 +92,7 @@ class ArchBundle:
 
 def params_spec_like(tree, fn) -> Any:
     """Build a sharding pytree by mapping fn(path_tuple, leaf_sds)->P."""
-    # jax.tree.flatten_with_path only exists in newer jax releases
-    flatten_with_path = getattr(
-        jax.tree, "flatten_with_path",
-        jax.tree_util.tree_flatten_with_path)
-    flat, treedef = flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     specs = [fn(tuple(str(k) for k in path), leaf) for path, leaf in flat]
     return jax.tree.unflatten(treedef, specs)
 

@@ -141,8 +141,10 @@ class KernelStats:
     Backends record one ``dispatch`` event per *compiled program launch*
     (jit'd compound primitives, Pallas kernels, fused chain programs) and
     one ``compile`` event per program they newly build; cheap eager glue
-    (takes, masks, pads, slices) is deliberately not recorded.  The engine
-    snapshots the ledger into ``ExecStats.kernels`` per run, so tests and
+    (takes, masks, pads, slices) is deliberately not recorded.  One label
+    counts a kernel rather than a launch: ``dispatch:wcoj`` is one call of
+    the Pallas WCOJ kernel, launched alone or inside a fused chain.  The
+    engine snapshots the ledger into ``ExecStats.kernels`` per run, so tests and
     benchmarks can assert dispatch counts — e.g. that a fused 3-hop chain
     executes as exactly one ``fused_chain`` dispatch (DESIGN.md §8)."""
 

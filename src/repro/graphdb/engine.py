@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 import operator as _op
+import sys
 import time
 
 import numpy as np
@@ -60,6 +61,15 @@ INT_MIN = np.iinfo(np.int64).min
 
 _CMP = {"=": _op.eq, "<>": _op.ne, "<": _op.lt, ">": _op.gt,
         "<=": _op.le, ">=": _op.ge}
+
+
+def _device_error(exc: BaseException) -> bool:
+    """A JAX compile or runtime failure.  ``JaxRuntimeError`` subclasses
+    ``RuntimeError``, so without this test a failing device would read as
+    a blow-up guard or a fallback reason.  Checked without importing jax:
+    if jax was never imported, no jax error can have been raised."""
+    jax = sys.modules.get("jax")
+    return jax is not None and isinstance(exc, jax.errors.JaxRuntimeError)
 
 
 @dataclasses.dataclass
@@ -237,7 +247,7 @@ class Engine:
 
     @staticmethod
     def _annotate_blowup(exc: RuntimeError, label: str):
-        if isinstance(exc, ExecError):
+        if isinstance(exc, ExecError) or _device_error(exc):
             raise exc        # structured failures keep their classification
         raise RuntimeError(f"{exc} in {label}") from None
 
@@ -949,7 +959,9 @@ class Engine:
                     # structured failures (deadline aborts, injected faults)
                     # belong to the containment layer, not the loop fallback
                     raise
-                except RuntimeError:
+                except RuntimeError as exc:
+                    if _device_error(exc):
+                        raise    # a failing device is not a tail limit
                     # fall back to the binding loop
                     reason = "stacked_tail_error"
             else:
@@ -1089,20 +1101,21 @@ class Engine:
                 if t.nrows:
                     parts.append(t.with_cols(
                         {"__seg": self.ops.full(t.nrows, i)}))
-            if not parts:
-                raise RuntimeError("stacked tail: all bindings empty")
-            self._params = {}
-            stacked = Table.concat(parts)
-            st.log("BATCH_BIND", stacked.nrows, time.perf_counter() - tb0)
-            for op in ops[1:]:
-                stacked = self._run_relational_seg(stacked, op, len(bound),
-                                                   st)
-            ts.set_phase("deliver")
-            host = self.ops.to_host(stacked)
+            seg = None       # all bindings empty: nothing to stack
+            if parts:
+                self._params = {}
+                stacked = Table.concat(parts)
+                st.log("BATCH_BIND", stacked.nrows,
+                       time.perf_counter() - tb0)
+                for op in ops[1:]:
+                    stacked = self._run_relational_seg(stacked, op,
+                                                       len(bound), st)
+                ts.set_phase("deliver")
+                host = self.ops.to_host(stacked)
+                seg = np.asarray(host.cols.pop("__seg"))
         finally:
             ts.set_phase("")
         tail_s = time.perf_counter() - tb0
-        seg = np.asarray(host.cols.pop("__seg"))
         window = ts.summary(bind_mark)
         kwindow = ks.summary(kbind)
         ewindow = es.summary(ebind)

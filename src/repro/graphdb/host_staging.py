@@ -137,13 +137,10 @@ class HostStagingOperators(NumpyOperators):
         d_max = _pow2(d_hi)
         R = rows_local.shape[0]
         rp = _pow2(R, _jb._MIN_BLOCK_ROWS)
-        block_rows = max(_jb._MIN_BLOCK_ROWS,
-                         min(rp, _jb._pow2_floor(_jb._TILE_ELEMS // d_max)))
         rows_p = self._pad_rows(rows_local, rp, 0).astype(np.int32)
         tgt_p = self._pad_rows(targets, rp, -2).astype(np.int32)
         adj = gather_rows(indices_d, indptr_d, self._up(rows_p), d_max)
         found_d, pos_d = self.inner._wcoj(adj, self._up(tgt_p),
-                                          block_rows=block_rows,
                                           interpret=self.inner._interpret)
         found = self._down(found_d)[:R].astype(bool)
         pos_in_row = self._down(pos_d)[:R].astype(np.int64)

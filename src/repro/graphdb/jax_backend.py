@@ -57,11 +57,9 @@ from repro.core.physical_spec import (CostParams, OperatorSet, PhysicalSpec,
 # compare-scan beats log-step gathers only while a row block fits in VMEM)
 MAX_ELL_DEGREE = 1024
 _MIN_BLOCK_ROWS = 8
-# rows per device slab: padded blocks are [slab, D_max]; slabbing bounds the
-# padded footprint and lets D_max adapt to each slab's real degree skew
+# rows per device slab: padded blocks are [D_max, slab]; slabbing bounds
+# the padded footprint and lets D_max adapt to each slab's real degree skew
 _SLAB_ROWS = 1 << 15
-# padded-block element budget per Pallas input tile (~2 MB of int32)
-_TILE_ELEMS = 1 << 19
 # element budget for one [rows, D_max] padded expand block.  The v2 expand
 # is a flat repeat-based CSR gather (no padded block, footprint == exact
 # output rows, capped by max_out), so this only governs the jit/TPU padded
@@ -185,14 +183,10 @@ class FusedChain:
                 ell = (d_hi > 0 and d_hi <= MAX_ELL_DEGREE
                        and (not self.ops._interpret
                             or (d_max <= 64 and caps[k] <= 4096)))
-                block_rows = max(_MIN_BLOCK_ROWS,
-                                 min(caps[k],
-                                     _pow2_floor(_TILE_ELEMS // d_max)))
                 probes.append((p.from_alias, p.edge_alias, p.orient.lo,
                                p.orient.hi, p.vlo, p.vhi,
                                p.orient.tidx, p.orient.csr.pos is not None,
-                               "ell" if ell else "bsearch", d_max,
-                               block_rows))
+                               "ell" if ell else "bsearch", d_max))
             hops.append((h.from_alias, h.alias, h.edge_alias, orients,
                          tuple(probes), sig(h.pred_sig)))
         return (spec.source, tuple(hops)), tuple(vprops), tuple(eprops)
@@ -224,12 +218,13 @@ class FusedChain:
             fn = ops._jax.jit(jaxops.build_fused_chain(
                 desc, self.caps, in_bucket, ops._interpret,
                 empty_values=empties))
-            entry = (fn, vprops, eprops)
+            n_ell = sum(pr[8] == "ell" for h in desc[1] for pr in h[4])
+            entry = (fn, vprops, eprops, n_ell)
             if len(self._progs) >= _CHAIN_PROGRAMS_PER_SHAPE:
                 self._progs.pop(next(iter(self._progs)))
             self._progs[key] = entry
             ops.kernel_stats.record("compile", "fused_chain")
-        fn, vprops, eprops = entry
+        fn, vprops, eprops, n_ell = entry
         src = jnp.asarray(src)
         if in_bucket > n:
             src = jnp.pad(src, (0, in_bucket - n))
@@ -254,6 +249,8 @@ class FusedChain:
         out, n0, needed, needed_f = fn(src, n, csrs, vp, ep, scal,
                                        tuple(vals))
         ops.kernel_stats.record("dispatch", "fused_chain")
+        if n_ell:
+            ops.kernel_stats.record("dispatch", "wcoj", n_ell)
         needed_h = np.asarray(needed)              # control-plane sync
         nf = np.asarray(needed_f)
         if nf.size and float(nf.max()) > _I32_MAX - 256:
@@ -630,7 +627,8 @@ class JaxOperators(OperatorSet):
                 founds.append(jnp.zeros(e - s, bool))
                 fposs.append(jnp.zeros(e - s, jnp.int32))
             elif d_hi <= MAX_ELL_DEGREE:
-                self.kernel_stats.record("dispatch", "intersect", 2)
+                self.kernel_stats.record("dispatch", "intersect")
+                self.kernel_stats.record("dispatch", "wcoj")
                 f, p = self._intersect_ell(indptr_d, indices_d, rows[s:e],
                                            tgt[s:e], d_hi)
                 founds.append(f)
@@ -658,16 +656,11 @@ class JaxOperators(OperatorSet):
         d_max = _pow2(d_hi)
         R = rows.shape[0]
         rp = _pow2(R, _MIN_BLOCK_ROWS)
-        # tile rows so one [block_rows, d_max] ELL block stays ~VMEM-sized
-        # (and interpret mode on CPU runs few, fat grid steps)
-        block_rows = max(_MIN_BLOCK_ROWS,
-                         min(rp, _pow2_floor(_TILE_ELEMS // d_max)))
         rows_p = self._pad(rows, rp)
         # pad targets with -2: never matches a real id (>=0) or ELL pad (-1)
         tgt_p = self._pad(targets, rp, -2)
         adj = gather_rows(indices_d, indptr_d, rows_p, d_max)
-        found_d, pos_d = self._wcoj(adj, tgt_p, block_rows=block_rows,
-                                    interpret=self._interpret)
+        found_d, pos_d = self._wcoj(adj, tgt_p, interpret=self._interpret)
         pos_in_row = pos_d[:R].astype(jnp.int32)
         return found_d[:R], self.take(indptr_d, rows) + pos_in_row
 

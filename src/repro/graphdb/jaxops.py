@@ -402,8 +402,8 @@ def build_fused_chain(desc: tuple, caps: tuple, in_bucket: int,
 
     ``desc`` = ``(source_col, hops)``; each hop is ``(from_col, alias,
     edge_alias, orients, probes, pred)`` with orients ``(lo, tidx,
-    has_pos)``, probes ``(from_col, edge_alias, lo, tidx, has_pos, mode,
-    d_max, block_rows)`` and ``pred`` a resolved predicate signature whose
+    has_pos)``, probes ``(from_col, edge_alias, lo, hi, vlo, vhi, tidx,
+    has_pos, mode, d_max)`` and ``pred`` a resolved predicate signature whose
     column refs are ``("col", name) | ("vprop", name, idx) | ("eprop",
     edge_alias, idx)`` and whose leaves read runtime slots.  The caller
     jits the result; the jit cache is keyed by (desc, caps, in_bucket)
@@ -503,7 +503,7 @@ def build_fused_chain(desc: tuple, caps: tuple, in_bucket: int,
             cols[f"{ealias}#p"] = acc_p
             valid = pos_out < jnp.minimum(running, cap)
             for pj, (p_from, p_ealias, lo, hi, vlo, vhi, tidx, has_pos,
-                     mode, d_max, block_rows) in enumerate(probes):
+                     mode, d_max) in enumerate(probes):
                 indptr, indices, pos = csrs[k][1][pj]
                 pfrm = cols[p_from]
                 local = jnp.clip(pfrm - lo, 0, indptr.shape[0] - 2)
@@ -516,7 +516,6 @@ def build_fused_chain(desc: tuple, caps: tuple, in_bucket: int,
                 if mode == "ell":
                     adj = gather_rows(indices, indptr, local, d_max)
                     found, prow = wcoj_intersect(adj, tgt,
-                                                 block_rows=block_rows,
                                                  interpret=interpret)
                     fpos = (jnp.take(indptr, local, axis=0, mode="clip")
                             + prow.astype(i32))

@@ -8,6 +8,8 @@ optimizer faces realistic skew. Deterministic per (sf, seed).
 """
 from __future__ import annotations
 
+import zlib
+
 import numpy as np
 
 from repro.core.schema import EdgeTriple, GraphSchema, ldbc_schema, motivating_schema
@@ -151,7 +153,8 @@ def _stream_chunks(seed: int, tag: tuple, total: int, fn):
     ranges, each with an independent ``SeedSequence((seed, *tag, chunk))``
     RNG.  Peak working memory is one chunk's output."""
     parts = []
-    key = [seed] + [hash(t) & 0x7FFFFFFF if isinstance(t, str) else t
+    # crc32, not hash(): str hashes are salted per process
+    key = [seed] + [zlib.crc32(t.encode()) if isinstance(t, str) else t
                     for t in tag]
     for ci, lo in enumerate(range(0, max(total, 0), _STREAM_CHUNK)):
         hi = min(lo + _STREAM_CHUNK, total)

@@ -175,6 +175,8 @@ class ServeStats:
         # window — a warmed server holds these flat at zero
         self.wave_compiles: list[int] = []
         self.wave_chain_compiles: list[int] = []
+        # KernelStats totals over every wave ({"kind:label": n})
+        self.kernels: dict[str, int] = {}
         self.per_plan: dict = {}               # cache_key -> summary dict
 
     # ------------------------------------------------------------ recording
@@ -194,6 +196,8 @@ class ServeStats:
                        if k.startswith("compile:"))
         self.wave_compiles.append(compiles)
         self.wave_chain_compiles.append(kernels.get("compile:fused_chain", 0))
+        for k, n in kernels.items():
+            self.kernels[k] = self.kernels.get(k, 0) + n
         plan = self._plan(key)
         plan["waves"] += 1
         plan["exec_s"].append(exec_s)
@@ -237,6 +241,7 @@ class ServeStats:
             "latency_p99_ms": _percentile(self.latency_s, 99) * 1e3,
             "fallbacks": dict(self.fallbacks),
             "compiles_per_wave": list(self.wave_compiles),
+            "kernels": dict(self.kernels),
             "failed": self.failed,
             "cancelled": self.cancelled,
             "retries": self.retries,

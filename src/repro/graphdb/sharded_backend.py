@@ -78,13 +78,11 @@ class ShardedOperators(JaxOperators):
     def __init__(self, store, devices: int | None = None):
         super().__init__(store)
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec
         avail = len(jax.devices())
         want = avail if devices is None else max(1, min(int(devices), avail))
         self.n_shards = _pow2_floor(want)
         self.mesh = Mesh(np.array(jax.devices()[:self.n_shards]), ("data",))
-        self._shard_map = shard_map
         self._P = PartitionSpec
         self._lax = jax.lax
         self._shards: dict[int, tuple[CsrShards, tuple]] = {}
@@ -97,13 +95,14 @@ class ShardedOperators(JaxOperators):
 
     def _smap(self, fn, in_specs, out_specs):
         import jax
-        # check_rep=False: psum/pmin/pmax outputs ARE replicated but the
-        # static replication checker can't infer it through searchsorted/
-        # while_loop bodies on this jax version
-        return jax.jit(self._shard_map(fn, mesh=self.mesh,
-                                       in_specs=in_specs,
-                                       out_specs=out_specs,
-                                       check_rep=False))
+        # check_vma=False: psum/pmin/pmax outputs ARE replicated, but the
+        # programs also replicate values that never pass through a
+        # collective (the all-shards-equal inputs of searchsorted /
+        # while_loop bodies), which the varying-axes type check rejects
+        return jax.jit(jax.shard_map(fn, mesh=self.mesh,
+                                     in_specs=in_specs,
+                                     out_specs=out_specs,
+                                     check_vma=False))
 
     def _prog(self, key: tuple, build):
         prog = self._progs.get(key)

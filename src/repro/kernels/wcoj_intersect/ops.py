@@ -1,5 +1,5 @@
-"""jit'd wrapper: picks the Pallas kernel (interpret on CPU, compiled on
-TPU) and handles the CSR -> padded-ELL row materialization."""
+"""The WCOJ probe's entry point and the CSR -> lane-dense padded-ELL row
+materialization it takes."""
 from __future__ import annotations
 
 import jax
@@ -8,20 +8,20 @@ import jax.numpy as jnp
 from repro.kernels.wcoj_intersect.wcoj_intersect import wcoj_intersect_pallas
 
 
-def wcoj_intersect(adj: jax.Array, target: jax.Array,
-                   block_rows: int = 256, interpret: bool | None = None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return wcoj_intersect_pallas(adj, target, block_rows=block_rows,
-                                 interpret=interpret)
+def wcoj_intersect(adj: jax.Array, target: jax.Array, *, interpret: bool):
+    """``interpret`` is the caller's to choose (the jax operator set runs
+    the kernel compiled on a TPU): nothing here falls back to interpret
+    mode on its own."""
+    return wcoj_intersect_pallas(adj, target, interpret=interpret)
 
 
 def gather_rows(indices: jax.Array, indptr: jax.Array, rows: jax.Array,
                 d_max: int) -> jax.Array:
-    """CSR rows -> padded ELL [R, d_max] (host-side prep for the kernel)."""
+    """CSR rows -> lane-dense padded ELL [d_max, R] (column ``i`` is row
+    ``rows[i]``'s adjacency, -1 padded): the kernel's input layout."""
     start = indptr[rows]
     deg = indptr[rows + 1] - start
-    offs = jnp.arange(d_max)[None, :]
-    valid = offs < deg[:, None]
-    flat = jnp.clip(start[:, None] + offs, 0, indices.shape[0] - 1)
+    offs = jnp.arange(d_max)[:, None]
+    valid = offs < deg[None, :]
+    flat = jnp.clip(start[None, :] + offs, 0, indices.shape[0] - 1)
     return jnp.where(valid, indices[flat], -1)

@@ -14,6 +14,8 @@ from repro.kernels.grouped_matmul.ops import grouped_matmul
 from repro.kernels.grouped_matmul.ref import grouped_matmul_ref
 from repro.kernels.wcoj_intersect.ops import gather_rows, wcoj_intersect
 from repro.kernels.wcoj_intersect.ref import wcoj_intersect_ref
+from repro.kernels.wcoj_intersect.wcoj_intersect import (TILE_ELEMS,
+                                                         block_rows_for)
 
 
 # ------------------------------------------------------------ wcoj_intersect
@@ -30,12 +32,31 @@ def test_wcoj_shapes(R, D):
     tgt = rng.integers(0, 5 * D, size=R).astype(np.int32)
     hit = deg > 0
     tgt[hit] = adj[np.arange(R), np.maximum(deg - 1, 0)][hit]
-    f1, p1 = wcoj_intersect(jnp.asarray(adj.astype(np.int32)),
-                            jnp.asarray(tgt), block_rows=64, interpret=True)
-    f2, p2 = wcoj_intersect_ref(jnp.asarray(adj.astype(np.int32)),
-                                jnp.asarray(tgt))
+    adj_t = jnp.asarray(adj.T.astype(np.int32))      # lane-dense [D, R]
+    f1, p1 = wcoj_intersect(adj_t, jnp.asarray(tgt), interpret=True)
+    f2, p2 = wcoj_intersect_ref(adj_t, jnp.asarray(tgt))
     np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2))
     np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
+
+
+@pytest.mark.parametrize("R,D", [(4100, 1024), (40000, 8), (300, 1)])
+def test_wcoj_default_tiles_match_ref(R, D):
+    """The kernel's own row tile (``block_rows_for``): legal for the TPU
+    (the whole row range, or lane-aligned within the VMEM budget) and
+    exact across a multi-step grid with a padded tail."""
+    blk = block_rows_for(R, D)
+    assert blk == R or (blk % 128 == 0 and blk * max(D, 8) <= TILE_ELEMS)
+    rng = np.random.default_rng(R + D)
+    adj = np.sort(rng.integers(0, 4 * D, size=(D, R)), axis=0)
+    adj = np.where(np.diff(adj, axis=0, prepend=-1) == 0, -1, adj)
+    tgt = rng.integers(0, 4 * D, size=R)
+    adj_t = jnp.asarray(adj.astype(np.int32))
+    f1, p1 = wcoj_intersect(adj_t, jnp.asarray(tgt, jnp.int32),
+                            interpret=True)
+    f2, p2 = wcoj_intersect_ref(adj_t, jnp.asarray(tgt, jnp.int32))
+    np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2))
+    np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
+    assert np.asarray(f1).any()
 
 
 def test_wcoj_from_csr(tiny_store):

@@ -272,6 +272,34 @@ def test_stacked_tail_error_falls_back_to_loop(serve_gopt, monkeypatch):
 
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_all_empty_batch_needs_no_fallback(serve_gopt, backend):
+    """A batch in which no binding matches anything stays on the stacked
+    path (each binding gets the loop's empty-input semantics there)."""
+    bindings = [{"pid": p} for p in (-1, -2, -3)]
+    pq = serve_gopt.prepare(Q.QIC["ic1"], backend=backend)
+    loop = pq.execute_many(bindings, batch=False)
+    batched = pq.execute_many(bindings, batch=True)
+    for (lt, _), (bt, bst) in zip(loop, batched):
+        _table_eq(lt, bt)
+        assert not bst.fallbacks, bst.fallbacks
+
+
+def test_device_error_in_stacked_tail_raises(serve_gopt, monkeypatch):
+    """A JAX runtime error (a ``RuntimeError`` subclass) out of the
+    segmented tail is a failing device, not a tail limit: it surfaces
+    instead of falling back to the per-binding loop."""
+    import jax
+    pq = serve_gopt.prepare(Q.QIC["ic1"], backend="jax")
+
+    def boom(self, *a, **k):
+        raise jax.errors.JaxRuntimeError("INTERNAL: injected device fault")
+
+    monkeypatch.setattr(Engine, "_run_tails_stacked", boom)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="injected"):
+        pq.execute_many([{"pid": p} for p in (1, 3, 5)], batch=True)
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_unstackable_tail_records_fallback(serve_gopt, backend):
     """A tail the segment pass cannot carry (string-literal output) runs
     the loop and says so in ``ExecStats.fallbacks``."""

@@ -236,6 +236,27 @@ def test_streamed_ldbc_deterministic():
         next(iter(GOpt(c).run(q)[0].cols.values())))
 
 
+def test_streamed_ldbc_same_across_processes():
+    """One seed, one store, whatever the process's string-hash salt."""
+    import os
+    import subprocess
+    import sys
+
+    from repro.graphdb.ldbc import generate_ldbc_streamed
+    code = ("from repro.graphdb.ldbc import generate_ldbc_streamed as g; "
+            "s = g(0.05); print(sum(int(c.indices.sum()) "
+            "for c in s.out_csr.values()))")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    sums = {int(subprocess.run(
+        [sys.executable, "-c", code], check=True, capture_output=True,
+        text=True, env={**os.environ, "PYTHONHASHSEED": salt,
+                        "PYTHONPATH": src}).stdout)
+        for salt in ("1", "2")}
+    s = generate_ldbc_streamed(0.05)
+    sums.add(sum(int(c.indices.sum()) for c in s.out_csr.values()))
+    assert len(sums) == 1, sums
+
+
 def test_streamed_ldbc_runs_appendix_queries():
     from repro.graphdb.ldbc import generate_ldbc_streamed
     g = GOpt(generate_ldbc_streamed(0.05))
