@@ -663,7 +663,8 @@ def run_serve(args) -> dict:
             if not _tables_equal(ref[k], r.table):
                 mismatches.append(f"{backend}/{name}{r.params}")
         s = srv.stats.summary()
-        warm_chain_compiles = sum(srv.stats.wave_chain_compiles)
+        warm_chain_compiles = srv.stats.kernels.get("compile:fused_chain",
+                                                    0)
 
         # containment overhead (DESIGN.md §13): the same saturated epoch
         # on the default contained path vs ``containment=False`` (the
@@ -721,7 +722,8 @@ def run_serve(args) -> dict:
             "deduped": s["deduped"],
             "fallbacks": s["fallbacks"],
             "warm_chain_compiles": warm_chain_compiles,
-            "compiles_per_wave": s["compiles_per_wave"],
+            "compiles": s["compiles"],
+            "waves_with_compiles": s["waves_with_compiles"],
             "containment_overhead": containment_overhead,
         }
         results.append(rec)

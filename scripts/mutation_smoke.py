@@ -106,13 +106,12 @@ def main():
     check(gopt.plan_cache_info()["epoch"] == epoch0 + 1,
           "compaction did not bump the stats epoch")
     check(ev["merged_edges"] > 0, f"nothing merged: {ev}")
-    n_waves = len(srv.stats.wave_chain_compiles)
+    chain = srv.stats.kernels.get("compile:fused_chain", 0)
     r3 = srv.submit(Q_KNOWS)
     srv.drain()
     check(rows(r3.table) == pre, "row parity broken by compaction")
-    post = srv.stats.wave_chain_compiles[n_waves:]
-    check(sum(post) == 0,
-          f"re-pinned server compiled {sum(post)} chain program(s)")
+    post = srv.stats.kernels.get("compile:fused_chain", 0) - chain
+    check(post == 0, f"re-pinned server compiled {post} chain program(s)")
     s = srv.stats.summary()
     srv.close()
     print(f"mutation smoke OK: {len(oracle)} isolated reads, "

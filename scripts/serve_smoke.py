@@ -92,7 +92,7 @@ def main():
     p99 = s["latency_p99_ms"]
     check(math.isfinite(p99) and 0 < p99 < 60_000,
           f"p99 latency out of bounds: {p99}ms")
-    warm_chain = sum(srv.stats.wave_chain_compiles)
+    warm_chain = srv.stats.kernels.get("compile:fused_chain", 0)
     check(warm_chain == 0,
           f"warmed server compiled {warm_chain} fused-chain program(s)")
     print(f"serve smoke OK: {s['completed']} requests over {s['waves']} "

@@ -148,18 +148,25 @@ class PreparedQuery:
     def declared_params(self) -> frozenset[str]:
         return frozenset(self.opt.logical.declared_params())
 
+    def _span(self, exec_kw: dict):
+        """The ``gopt.execute`` span, on the operator set this execution
+        runs on: the replan check, binding and engine set-up."""
+        spec = get_spec(exec_kw.get("backend") or self.spec)
+        return spec.operators(self.gopt.store).span("gopt.execute")
+
     def execute(self, params: dict | None = None,
                 **exec_kw) -> tuple[Table, ExecStats]:
-        # binding-skew guard: a binding whose IN-set cardinality diverges
-        # >10x from the build-time peek invalidates this cache entry and
-        # re-plans once against the actual binding
-        pq = self.gopt._maybe_replan(self, params)
-        if pq is not self:
-            return pq.execute(params, **exec_kw)
-        self.executions += 1
-        return self.gopt.execute(self.opt, params=params,
-                                 backend=exec_kw.pop("backend", self.spec),
-                                 **exec_kw)
+        with self._span(exec_kw):
+            # binding-skew guard: a binding whose IN-set cardinality
+            # diverges >10x from the build-time peek invalidates this cache
+            # entry and re-plans once against the actual binding
+            pq = self.gopt._maybe_replan(self, params)
+            if pq is not self:
+                return pq.execute(params, **exec_kw)
+            self.executions += 1
+            return self.gopt.execute(
+                self.opt, params=params,
+                backend=exec_kw.pop("backend", self.spec), **exec_kw)
 
     def execute_many(self, bindings: list[dict | None], batch: bool = True,
                      **exec_kw) -> list[tuple[Table, ExecStats]]:
@@ -177,8 +184,9 @@ class PreparedQuery:
             kw = dict(exec_kw)
             backend = kw.pop("backend", self.spec)
             try:
-                out = self.gopt.execute_batch(self.opt, bindings,
-                                              backend=backend, **kw)
+                with self._span(exec_kw):
+                    out = self.gopt.execute_batch(self.opt, bindings,
+                                                  backend=backend, **kw)
                 self.executions += len(bindings)
                 return out
             except RuntimeError as exc:

@@ -38,6 +38,7 @@ suite checks semantics *and* the row-order contract against tiny oracles.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import importlib
 from typing import Callable
@@ -84,8 +85,8 @@ class TransferStats:
     crosses the boundary; the engine tags the current execution phase
     (``"pattern"`` / ``"tail"`` / ``"deliver"``) so tests and benchmarks can
     assert the residency invariant: zero ``d2h`` outside ``deliver``.
-    Scalar control-plane syncs (row counts, blow-up guards) are *not*
-    transfers and are not recorded."""
+    Scalar control-plane syncs (row counts, blow-up guards) are not data
+    transfers: they are recorded in ``KernelStats`` as ``sync`` events."""
 
     def __init__(self):
         self.phase = ""
@@ -143,7 +144,10 @@ class KernelStats:
     one ``compile`` event per program they newly build; cheap eager glue
     (takes, masks, pads, slices) is deliberately not recorded.  One label
     counts a kernel rather than a launch: ``dispatch:wcoj`` is one call of
-    the Pallas WCOJ kernel, launched alone or inside a fused chain.  The
+    the Pallas WCOJ kernel, launched alone or inside a fused chain.  A
+    ``sync`` event is one device->host round trip of control-plane scalars
+    (``sync:expand`` sizes an expansion's output; the host waits for the
+    device there).  The
     engine snapshots the ledger into ``ExecStats.kernels`` per run, so tests and
     benchmarks can assert dispatch counts — e.g. that a fused 3-hop chain
     executes as exactly one ``fused_chain`` dispatch (DESIGN.md §8)."""
@@ -437,6 +441,27 @@ class OperatorSet:
         until every array in ``arrays`` (any pytree) is computed.  Host
         backends are synchronous — the default is a no-op."""
         return arrays
+
+    # ---------------------------------------------------------------- spans
+    def span(self, name: str, **args):
+        """A named span on the profiler's timeline around the work this
+        operator set runs (``gopt.wave``, ``gopt.op.EXPAND``, ...; names
+        from a bounded set, never aliases or bindings).  A host backend
+        has no device timeline to share: a no-op here."""
+        return contextlib.nullcontext()
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        """One engine execution phase (``pattern`` / ``tail`` /
+        ``deliver``): tags ``transfer_stats`` and opens the ``gopt.<name>``
+        span together, so the transfer phases and the span phases cannot
+        disagree."""
+        self.transfer_stats.set_phase(name)
+        try:
+            with self.span("gopt." + name):
+                yield
+        finally:
+            self.transfer_stats.set_phase("")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -19,7 +19,10 @@ The engine also meters the paper's cost-model quantities: rows produced per
 operator (communication-cost analogue) and per-operator wall time
 (``ExecStats.op_rows`` / ``op_times``; on asynchronously-dispatching
 backends the per-operator times are dispatch times — the final sync is
-absorbed by delivery).
+absorbed by delivery).  Each metered operator runs inside one
+``gopt.op.<KIND>`` span (``_Op``) and each phase inside a
+``gopt.<phase>`` span (``OperatorSet.phase``): the spans and PROFILE's
+numbers come from the same place.
 
 Modes (used by the RBO ablation benchmarks):
 - ``fuse_expand``   — ExpandGetVFusionRule on/off: fused neighbor expansion vs
@@ -164,6 +167,31 @@ class ExecStats:
         self.fallbacks[reason] = self.fallbacks.get(reason, 0) + n
 
 
+class _Op:
+    """One metered operator, ``with _Op(engine, "SCAN", stats) as op: ...
+    op.log(label, tbl)``: its ``gopt.op.<KIND>`` span, and the ``log``
+    that records its rows and time in ``ExecStats`` (timed from the span's
+    start, synced first under ``sync_per_op``).  Every operator that
+    ``ExecStats`` meters runs under one."""
+    __slots__ = ("_eng", "_stats", "_span", "_t0")
+
+    def __init__(self, eng: "Engine", kind: str, stats: ExecStats):
+        self._eng, self._stats = eng, stats
+        self._span = eng.ops.span("gopt.op." + kind)
+
+    def __enter__(self) -> "_Op":
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        return self._span.__exit__(*exc)
+
+    def log(self, label: str, tbl: "Table | None"):
+        self._stats.log(label, tbl.nrows if tbl is not None else 0,
+                        self._eng._tick(tbl, self._t0))
+
+
 class Engine:
     def __init__(self, store: GraphStore, fuse_expand: bool = True,
                  trim_fields: bool = True, max_rows: int = 100_000_000,
@@ -252,29 +280,30 @@ class Engine:
         raise RuntimeError(f"{exc} in {label}") from None
 
     def _scan(self, pattern: Pattern, alias: str, stats: ExecStats) -> Table:
-        t0 = time.perf_counter()
-        v = pattern.vertices[alias]
-        parts = []
-        for t in sorted(v.types):
-            lo, hi = self.store.type_range(t)
-            ids = self.ops.scan(lo, hi)
-            if self._delta:
-                # snapshot view: drop tombstoned ids, append extension ids
-                # (new vertices live above the base id space, per type)
-                dead = self.snapshot.dead_for(t)
-                if dead is not None:
-                    keep = ~self.ops.isin(ids, list(dead))
-                    ids = self.ops.take(ids, self.ops.nonzero(keep))
-                ext = self.snapshot.ext.get(t)
-                if ext is not None:
-                    parts.append(ids)
-                    parts.append(self.ops.asarray(ext))
-                    continue
-            parts.append(ids)
-        ids = self.ops.concat(parts)
-        tbl = self._table({alias: ids}, int(ids.shape[0]))
-        tbl = self._apply_fused_predicates(tbl, v.predicates, stats)
-        stats.log(f"SCAN({alias})", tbl.nrows, self._tick(tbl, t0))
+        with _Op(self, "SCAN", stats) as op:
+            v = pattern.vertices[alias]
+            parts = []
+            for t in sorted(v.types):
+                lo, hi = self.store.type_range(t)
+                ids = self.ops.scan(lo, hi)
+                if self._delta:
+                    # snapshot view: drop tombstoned ids, append extension
+                    # ids (new vertices live above the base id space, per
+                    # type)
+                    dead = self.snapshot.dead_for(t)
+                    if dead is not None:
+                        keep = ~self.ops.isin(ids, list(dead))
+                        ids = self.ops.take(ids, self.ops.nonzero(keep))
+                    ext = self.snapshot.ext.get(t)
+                    if ext is not None:
+                        parts.append(ids)
+                        parts.append(self.ops.asarray(ext))
+                        continue
+                parts.append(ids)
+            ids = self.ops.concat(parts)
+            tbl = self._table({alias: ids}, int(ids.shape[0]))
+            tbl = self._apply_fused_predicates(tbl, v.predicates, stats)
+            op.log(f"SCAN({alias})", tbl)
         self._materialize(tbl, alias, pattern)
         return tbl
 
@@ -539,20 +568,37 @@ class Engine:
             return self._scan(pattern, node.alias, stats)
         if isinstance(node, ExpandNode):
             tbl = self.exec_pattern(pattern, node.child, stats)
-            t0 = time.perf_counter()
-            edges = list(node.edges)
-            # primary expansion via the first edge
-            e0 = edges[0]
-            frm = e0.other(node.new_alias)
-            if self.fuse_expand:
-                tbl = self._expand_edge(tbl, pattern, e0, frm,
-                                        node.new_alias, stats)
-            else:
-                # EXPAND_EDGE then a separate GET_VERTEX pass: endpoint ids
-                # are re-resolved from the edge bindings and re-type-checked
-                # (the work ExpandGetVFusionRule eliminates)
-                tbl = self._expand_edge(tbl, pattern, e0, frm,
-                                        node.new_alias, stats)
+            with _Op(self, "EXPAND", stats) as op:
+                tbl = self._expand_node(tbl, pattern, node, stats)
+                op.log(f"EXPAND(+{node.new_alias}|{len(node.edges)}e)", tbl)
+            self._materialize(tbl, node.new_alias, pattern)
+            return tbl
+        if isinstance(node, ExpandChainNode):
+            if not self.fuse_expand:
+                # ExpandGetVFusion ablation: run the pre-fusion plan
+                return self.exec_pattern(pattern, node.unfused(), stats)
+            tbl = self.exec_pattern(pattern, node.child, stats)
+            return self._exec_chain(pattern, node, tbl, stats)
+        if isinstance(node, JoinNode):
+            lt = self.exec_pattern(pattern, node.left, stats)
+            rt = self.exec_pattern(pattern, node.right, stats)
+            return self._exec_join(pattern, node, lt, rt, stats)
+        raise TypeError(node)
+
+    def _expand_node(self, tbl: Table, pattern: Pattern, node: ExpandNode,
+                     stats: ExecStats) -> Table:
+        """One ExpandNode: the primary expansion through its first edge,
+        then a WCOJ membership probe per remaining edge, then the fused
+        predicates."""
+        edges = list(node.edges)
+        e0 = edges[0]
+        frm = e0.other(node.new_alias)
+        tbl = self._expand_edge(tbl, pattern, e0, frm, node.new_alias, stats)
+        if not self.fuse_expand:
+            # EXPAND_EDGE then a separate GET_VERTEX pass: endpoint ids are
+            # re-resolved from the edge bindings and re-type-checked (the
+            # work ExpandGetVFusionRule eliminates)
+            with _Op(self, "GET_VERTEX", stats) as op:
                 if tbl.nrows:
                     nbr = tbl.cols[node.new_alias]
                     types = self.store._sorted_types()
@@ -567,32 +613,15 @@ class Engine:
                             node.new_alias].types
                     tbl = tbl.mask(self.ops.take(self.ops.asarray(allowed),
                                                  tidx))
-                stats.log(f"GET_VERTEX({node.new_alias})", tbl.nrows,
-                          self._tick(tbl, t0))
-            # intersect the remaining edges (WCOJ step)
-            for e in edges[1:]:
-                frm = e.other(node.new_alias)
-                tbl = self._intersect_edge(tbl, pattern, e, frm,
-                                           node.new_alias)
-            v = pattern.vertices[node.new_alias]
-            tbl = self._apply_fused_predicates(tbl, v.predicates, stats)
-            for e in edges:
-                tbl = self._apply_fused_predicates(tbl, e.predicates, stats)
-            stats.log(f"EXPAND(+{node.new_alias}|{len(edges)}e)", tbl.nrows,
-                      self._tick(tbl, t0))
-            self._materialize(tbl, node.new_alias, pattern)
-            return tbl
-        if isinstance(node, ExpandChainNode):
-            if not self.fuse_expand:
-                # ExpandGetVFusion ablation: run the pre-fusion plan
-                return self.exec_pattern(pattern, node.unfused(), stats)
-            tbl = self.exec_pattern(pattern, node.child, stats)
-            return self._exec_chain(pattern, node, tbl, stats)
-        if isinstance(node, JoinNode):
-            lt = self.exec_pattern(pattern, node.left, stats)
-            rt = self.exec_pattern(pattern, node.right, stats)
-            return self._exec_join(pattern, node, lt, rt, stats)
-        raise TypeError(node)
+                op.log(f"GET_VERTEX({node.new_alias})", tbl)
+        for e in edges[1:]:
+            frm = e.other(node.new_alias)
+            tbl = self._intersect_edge(tbl, pattern, e, frm, node.new_alias)
+        v = pattern.vertices[node.new_alias]
+        tbl = self._apply_fused_predicates(tbl, v.predicates, stats)
+        for e in edges:
+            tbl = self._apply_fused_predicates(tbl, e.predicates, stats)
+        return tbl
 
     # ================================================================= chains
     def _chain_spec(self, node: ExpandChainNode, pattern: Pattern):
@@ -644,10 +673,18 @@ class Engine:
         is in the fusable envelope; otherwise (and on the first, measuring
         execution of a shape) the thin-frontier per-hop loop — the parity
         oracle the fused program is held to."""
-        t0 = time.perf_counter()
+        label = "EXPANDCHAIN(" + "".join(f"+{s.alias}" for s in node.steps) \
+            + ")"
+        with _Op(self, "EXPANDCHAIN", stats) as op:
+            out = self._chain_table(pattern, node, tbl, stats, label)
+            op.log(label, out)
+        for s in node.steps:
+            self._materialize(out, s.alias, pattern)
+        return out
+
+    def _chain_table(self, pattern: Pattern, node: ExpandChainNode,
+                     tbl: Table, stats: ExecStats, label: str) -> Table:
         first = node.steps[0].from_alias
-        hops = "".join(f"+{s.alias}" for s in node.steps)
-        label = f"EXPANDCHAIN({hops})"
         prog = None
         delta_decline = False
         if self._delta:
@@ -679,11 +716,7 @@ class Engine:
                 self._annotate_blowup(exc, label)
             if res is not None:
                 rows, cols, n = res
-                out = tbl.take(rows).with_cols(cols) if n else Table.empty()
-                stats.log(label, out.nrows, self._tick(out, t0))
-                for s in node.steps:
-                    self._materialize(out, s.alias, pattern)
-                return out
+                return tbl.take(rows).with_cols(cols) if n else Table.empty()
         # per-hop loop: thin frontier (source column, hop columns, a
         # provenance row index), full table gathered once at the end
         cur = self._table({first: tbl.cols[first],
@@ -708,37 +741,32 @@ class Engine:
         if prog is not None:
             prog.observe(sizes)         # fix/regrow the capacity schedule
         if cur.nrows == 0:
-            stats.log(label, 0, self._tick(None, t0))
             return Table.empty()
         rows = cur.cols.pop("__chain_row")
         del cur.cols[first]          # tbl carries the original column
-        out = tbl.take(rows).with_cols(cur.cols)
-        stats.log(label, out.nrows, self._tick(out, t0))
-        for s in node.steps:
-            self._materialize(out, s.alias, pattern)
-        return out
+        return tbl.take(rows).with_cols(cur.cols)
 
     def _exec_join(self, pattern: Pattern, node: JoinNode, lt: Table,
                    rt: Table, stats: ExecStats) -> Table:
-        t0 = time.perf_counter()
-        # join on the shared vertex aliases plus any other column both
-        # sides bound (shared edges must bind identically on both sides)
-        keys = sorted(set(node.keys) |
-                      (set(lt.cols) & set(rt.cols) - {"__pad"}))
-        keys = [k for k in keys if not k.startswith("__mat.")]
-        label = f"JOIN({'/'.join(keys) or 'cross'})"
-        lkey, rkey = self._pack_join_keys(lt, rt, keys)
-        try:
-            lidx, ridx = self.ops.join(lkey, rkey, max_out=self.max_rows)
-        except RuntimeError as exc:
-            self._annotate_blowup(exc, label)
-        self._check(int(lidx.shape[0]), label)
-        cols = {k: self.ops.take(v, lidx) for k, v in lt.cols.items()}
-        for k, v in rt.cols.items():
-            if k not in cols:
-                cols[k] = self.ops.take(v, ridx)
-        out = self._table(cols, int(lidx.shape[0]))
-        stats.log(f"JOIN({'/'.join(keys)})", out.nrows, self._tick(out, t0))
+        with _Op(self, "JOIN", stats) as op:
+            # join on the shared vertex aliases plus any other column both
+            # sides bound (shared edges must bind identically on both sides)
+            keys = sorted(set(node.keys) |
+                          (set(lt.cols) & set(rt.cols) - {"__pad"}))
+            keys = [k for k in keys if not k.startswith("__mat.")]
+            label = f"JOIN({'/'.join(keys) or 'cross'})"
+            lkey, rkey = self._pack_join_keys(lt, rt, keys)
+            try:
+                lidx, ridx = self.ops.join(lkey, rkey, max_out=self.max_rows)
+            except RuntimeError as exc:
+                self._annotate_blowup(exc, label)
+            self._check(int(lidx.shape[0]), label)
+            cols = {k: self.ops.take(v, lidx) for k, v in lt.cols.items()}
+            for k, v in rt.cols.items():
+                if k not in cols:
+                    cols[k] = self.ops.take(v, ridx)
+            out = self._table(cols, int(lidx.shape[0]))
+            op.log(f"JOIN({'/'.join(keys)})", out)
         return out
 
     def _pack_join_keys(self, lt: Table, rt: Table, keys: list[str]):
@@ -888,16 +916,13 @@ class Engine:
         kmark = ks.mark()
         emark = es.mark()
         fmark = fs.mark()
-        ts.set_phase("pattern")
-        try:
+        with self.ops.phase("pattern"):
             tbl = self.exec_pattern(pattern, node, stats)
-            ts.set_phase("tail")
+        with self.ops.phase("tail"):
             for op in ops[1:]:
                 tbl = self._run_relational(tbl, op, stats)
-            ts.set_phase("deliver")
+        with self.ops.phase("deliver"):
             tbl = self.ops.to_host(tbl)
-        finally:
-            ts.set_phase("")
         stats.wall_s = time.perf_counter() - t0
         stats.transfers = ts.summary(mark)
         stats.kernels = ks.summary(kmark)
@@ -933,12 +958,11 @@ class Engine:
         self._batch = bound
         self._deferred = []
         self._params = {}
-        ts.set_phase("pattern")
         try:
-            tbl = self.exec_pattern(pattern, node, shared)
+            with self.ops.phase("pattern"):
+                tbl = self.exec_pattern(pattern, node, shared)
         finally:
             self._batch = None
-            ts.set_phase("")
         pattern_s = time.perf_counter() - t0
         # the shared pattern phase's transfers belong to every binding; the
         # per-binding window starts fresh so binding i never reads binding
@@ -1042,16 +1066,14 @@ class Engine:
                            fallbacks=dict(shared.fallbacks))
             if reason is not None:
                 st.fallback(reason)
-            ts.set_phase("tail")
-            try:
-                t = self._refilter(tbl, deferred, b)
-                st.log("BATCH_BIND", t.nrows, time.perf_counter() - tb0)
+            with self.ops.phase("tail"):
+                with _Op(self, "BATCH_BIND", st) as bind:
+                    t = self._refilter(tbl, deferred, b)
+                    bind.log("BATCH_BIND", t)
                 for op in ops[1:]:
                     t = self._run_relational(t, op, st)
-                ts.set_phase("deliver")
+            with self.ops.phase("deliver"):
                 t = self.ops.to_host(t)
-            finally:
-                ts.set_phase("")
             st.wall_s = pattern_s + (time.perf_counter() - tb0)
             st.transfers = {k: dict(v) for k, v in pattern_transfers.items()}
             for k, v in ts.summary(bind_mark).items():
@@ -1092,29 +1114,27 @@ class Engine:
                        op_rows=list(shared.op_rows),
                        op_times=list(shared.op_times),
                        fallbacks=dict(shared.fallbacks))
-        ts.set_phase("tail")
-        try:
-            parts, counts = [], []
-            for i, b in enumerate(bound):
-                t = self._refilter(tbl, deferred, b)
-                counts.append(t.nrows)
-                if t.nrows:
-                    parts.append(t.with_cols(
-                        {"__seg": self.ops.full(t.nrows, i)}))
-            seg = None       # all bindings empty: nothing to stack
+        seg = None       # all bindings empty: nothing to stack
+        with self.ops.phase("tail"):
+            with _Op(self, "BATCH_BIND", st) as bind:
+                parts, counts = [], []
+                for i, b in enumerate(bound):
+                    t = self._refilter(tbl, deferred, b)
+                    counts.append(t.nrows)
+                    if t.nrows:
+                        parts.append(t.with_cols(
+                            {"__seg": self.ops.full(t.nrows, i)}))
+                stacked = Table.concat(parts) if parts else None
+                bind.log("BATCH_BIND", stacked)
             if parts:
                 self._params = {}
-                stacked = Table.concat(parts)
-                st.log("BATCH_BIND", stacked.nrows,
-                       time.perf_counter() - tb0)
                 for op in ops[1:]:
                     stacked = self._run_relational_seg(stacked, op,
                                                        len(bound), st)
-                ts.set_phase("deliver")
+        if parts:
+            with self.ops.phase("deliver"):
                 host = self.ops.to_host(stacked)
-                seg = np.asarray(host.cols.pop("__seg"))
-        finally:
-            ts.set_phase("")
+            seg = np.asarray(host.cols.pop("__seg"))
         tail_s = time.perf_counter() - tb0
         window = ts.summary(bind_mark)
         kwindow = ks.summary(kbind)
@@ -1129,11 +1149,14 @@ class Engine:
                                 op_rows=list(shared.op_rows),
                                 op_times=list(shared.op_times),
                                 fallbacks=dict(shared.fallbacks))
-                bst.log("BATCH_BIND", 0, 0.0)
-                for op in ops[1:]:
-                    t = self._run_relational(t, op, bst)
+                with self.ops.phase("tail"):
+                    with _Op(self, "BATCH_BIND", bst) as bind:
+                        bind.log("BATCH_BIND", None)
+                    for op in ops[1:]:
+                        t = self._run_relational(t, op, bst)
                 if t.ops is not None:
-                    t = self.ops.to_host(t)
+                    with self.ops.phase("deliver"):
+                        t = self.ops.to_host(t)
             else:
                 m = seg == i
                 t = Table({k: v[m] for k, v in host.cols.items()},
@@ -1175,41 +1198,43 @@ class Engine:
         the plain operator on that segment alone.  The stack is segment-
         major throughout (every operator preserves or re-establishes it)."""
         self._check_deadline(type(op).__name__)
-        t0 = time.perf_counter()
         seg = tbl.cols["__seg"]
         if isinstance(op, ir.Select):
-            if tbl.nrows:
-                tbl = tbl.mask(self._eval(tbl, op.predicate).astype(bool))
-            stats.log("SELECT", tbl.nrows, self._tick(tbl, t0))
+            with _Op(self, "SELECT", stats) as o:
+                if tbl.nrows:
+                    tbl = tbl.mask(self._eval(tbl, op.predicate).astype(bool))
+                o.log("SELECT", tbl)
             return tbl
         if isinstance(op, ir.Project):
-            cols = {name: (self._eval(tbl, e) if tbl.nrows
-                           else self.ops.full(0, 0))
-                    for e, name in op.items}
-            cols["__seg"] = seg
-            out = self._table(cols, tbl.nrows)
-            if op.distinct and out.nrows:
-                key = self.ops.combine_keys(list(out.cols.values()))
-                out = out.take(self.ops.distinct_indices(key))
-            stats.log("PROJECT", out.nrows, self._tick(out, t0))
+            with _Op(self, "PROJECT", stats) as o:
+                cols = {name: (self._eval(tbl, e) if tbl.nrows
+                               else self.ops.full(0, 0))
+                        for e, name in op.items}
+                cols["__seg"] = seg
+                out = self._table(cols, tbl.nrows)
+                if op.distinct and out.nrows:
+                    key = self.ops.combine_keys(list(out.cols.values()))
+                    out = out.take(self.ops.distinct_indices(key))
+                o.log("PROJECT", out)
             return out
         if isinstance(op, ir.GroupBy):
             if tbl.nrows == 0:   # empty-input fix-ups are per-binding
                 raise RuntimeError("stacked tail: stack emptied")
-            kcols = [self._eval(tbl, e) for e, _ in op.keys]
-            key = self.ops.combine_keys([seg] + kcols)
-            vals = {}
-            for a, name in op.aggs:
-                col = (self._eval(tbl, a.arg) if a.arg is not None
-                       else self.ops.full(tbl.nrows, 0))
-                vals[name] = (a.fn, col)
-            first, aggd = self.ops.group_reduce(key, vals)
-            cols = {name: self.ops.take(kc, first)
-                    for (e, name), kc in zip(op.keys, kcols)}
-            cols.update(aggd)
-            cols["__seg"] = self.ops.take(seg, first)
-            out = self._table(cols, int(first.shape[0]))
-            stats.log("GROUP", out.nrows, self._tick(out, t0))
+            with _Op(self, "GROUP", stats) as o:
+                kcols = [self._eval(tbl, e) for e, _ in op.keys]
+                key = self.ops.combine_keys([seg] + kcols)
+                vals = {}
+                for a, name in op.aggs:
+                    col = (self._eval(tbl, a.arg) if a.arg is not None
+                           else self.ops.full(tbl.nrows, 0))
+                    vals[name] = (a.fn, col)
+                first, aggd = self.ops.group_reduce(key, vals)
+                cols = {name: self.ops.take(kc, first)
+                        for (e, name), kc in zip(op.keys, kcols)}
+                cols.update(aggd)
+                cols["__seg"] = self.ops.take(seg, first)
+                out = self._table(cols, int(first.shape[0]))
+                o.log("GROUP", out)
             return out
         if isinstance(op, ir.OrderBy):
             if tbl.nrows == 0:
@@ -1236,21 +1261,22 @@ class Engine:
 
     def _run_relational(self, tbl: Table, op, stats: ExecStats) -> Table:
         self._check_deadline(type(op).__name__)
-        t0 = time.perf_counter()
         if isinstance(op, ir.Select):
-            if tbl.nrows:
-                tbl = tbl.mask(self._eval(tbl, op.predicate).astype(bool))
-            stats.log("SELECT", tbl.nrows, self._tick(tbl, t0))
+            with _Op(self, "SELECT", stats) as o:
+                if tbl.nrows:
+                    tbl = tbl.mask(self._eval(tbl, op.predicate).astype(bool))
+                o.log("SELECT", tbl)
             return tbl
         if isinstance(op, ir.Project):
-            cols = {name: (self._eval(tbl, e) if tbl.nrows
-                           else self.ops.full(0, 0))
-                    for e, name in op.items}
-            out = self._table(cols, tbl.nrows)
-            if op.distinct and out.nrows:
-                key = self.ops.combine_keys(list(out.cols.values()))
-                out = out.take(self.ops.distinct_indices(key))
-            stats.log("PROJECT", out.nrows, self._tick(out, t0))
+            with _Op(self, "PROJECT", stats) as o:
+                cols = {name: (self._eval(tbl, e) if tbl.nrows
+                               else self.ops.full(0, 0))
+                        for e, name in op.items}
+                out = self._table(cols, tbl.nrows)
+                if op.distinct and out.nrows:
+                    key = self.ops.combine_keys(list(out.cols.values()))
+                    out = out.take(self.ops.distinct_indices(key))
+                o.log("PROJECT", out)
             return out
         if isinstance(op, ir.GroupBy):
             if tbl.nrows == 0:
@@ -1261,20 +1287,21 @@ class Engine:
                         return Table({n: np.array([0], np.int64)}, 1)
                     cols[n] = np.zeros(0, np.int64)
                 return Table(cols, 0)
-            kcols = [self._eval(tbl, e) for e, _ in op.keys]
-            key = (self.ops.combine_keys(kcols) if kcols
-                   else self.ops.full(tbl.nrows, 0))
-            vals = {}
-            for a, name in op.aggs:
-                col = (self._eval(tbl, a.arg) if a.arg is not None
+            with _Op(self, "GROUP", stats) as o:
+                kcols = [self._eval(tbl, e) for e, _ in op.keys]
+                key = (self.ops.combine_keys(kcols) if kcols
                        else self.ops.full(tbl.nrows, 0))
-                vals[name] = (a.fn, col)
-            first, aggd = self.ops.group_reduce(key, vals)
-            cols = {name: self.ops.take(kc, first)
-                    for (e, name), kc in zip(op.keys, kcols)}
-            cols.update(aggd)
-            out = self._table(cols, int(first.shape[0]))
-            stats.log("GROUP", out.nrows, self._tick(out, t0))
+                vals = {}
+                for a, name in op.aggs:
+                    col = (self._eval(tbl, a.arg) if a.arg is not None
+                           else self.ops.full(tbl.nrows, 0))
+                    vals[name] = (a.fn, col)
+                first, aggd = self.ops.group_reduce(key, vals)
+                cols = {name: self.ops.take(kc, first)
+                        for (e, name), kc in zip(op.keys, kcols)}
+                cols.update(aggd)
+                out = self._table(cols, int(first.shape[0]))
+                o.log("GROUP", out)
             return out
         if isinstance(op, ir.OrderBy):
             if tbl.nrows == 0:

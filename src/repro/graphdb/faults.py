@@ -247,6 +247,9 @@ class FaultyOperatorSet(OperatorSet):
     def block_ready(self, arrays):
         return self.inner.block_ready(arrays)
 
+    def span(self, name: str, **args):
+        return self.inner.span(name, **args)
+
 
 def _delegator(name: str, inject: bool, wildcard: bool = True):
     def method(self, *args, **kwargs):

@@ -37,8 +37,9 @@ sizes re-hit one compiled program per bucket, counter-proved by the
 Vertex ids, CSR offsets and property columns
 stage through int32 (guarded at construction); ``to_host`` widens back to
 int64 and canonicalizes the missing-property sentinel.  Control-plane
-scalar syncs (row counts, blow-up guards) are not data transfers and are
-not recorded.
+scalar syncs (row counts, blow-up guards) are not data transfers: each
+goes through ``JaxOperators._sync``, which records a ``sync`` event in
+``KernelStats`` and opens a ``gopt.sync.<label>`` span.
 """
 from __future__ import annotations
 
@@ -251,8 +252,7 @@ class FusedChain:
         ops.kernel_stats.record("dispatch", "fused_chain")
         if n_ell:
             ops.kernel_stats.record("dispatch", "wcoj", n_ell)
-        needed_h = np.asarray(needed)              # control-plane sync
-        nf = np.asarray(needed_f)
+        needed_h, nf, n0 = ops._sync("fused_chain", (needed, needed_f, n0))
         if nf.size and float(nf.max()) > _I32_MAX - 256:
             raise RuntimeError(
                 f"intermediate blow-up: chain expansion would produce "
@@ -357,6 +357,19 @@ class JaxOperators(OperatorSet):
     def block_ready(self, arrays):
         return self._jax.block_until_ready(arrays)
 
+    def span(self, name: str, **args):
+        return self._jax.profiler.TraceAnnotation(name, **args)
+
+    def _sync(self, label: str, x):
+        """The one device->host round trip of control-plane scalars (row
+        counts, blow-up guards): ``x`` is a device value or a tuple of
+        them, fetched together as host numpy.  Records ``sync:<label>`` in
+        ``KernelStats`` and opens a ``gopt.sync.<label>`` span, so the
+        count and the trace name every place the host waits."""
+        self.kernel_stats.record("sync", label)
+        with self.span("gopt.sync." + label):
+            return self._jax.device_get(x)
+
     # ------------------------------------------------------------ transfers
     def asarray(self, values):
         if isinstance(values, self._jax.Array):
@@ -369,7 +382,8 @@ class JaxOperators(OperatorSet):
         if not isinstance(a, self._jax.Array):
             return np.asarray(a)
         self.transfer_stats.record("d2h", a.size)
-        h = np.asarray(a)
+        with self.span("gopt.d2h"):
+            h = np.asarray(a)
         if h.dtype == np.int32:
             h64 = h.astype(np.int64)
             h64[h64 == _I32_MIN] = _I64_MIN   # missing-prop sentinel widens
@@ -418,7 +432,7 @@ class JaxOperators(OperatorSet):
         if m.dtype != bool:
             m = m != 0          # int 0/1 masks: sum/argsort need real bools
         n = m.shape[0]
-        cnt = int(m.sum())                           # control-plane sync
+        cnt = int(self._sync("nonzero", m.sum()))
         if cnt == 0:
             return jnp.zeros(0, jnp.int32)
         np2 = _pow2(n, _TAIL_MIN_BUCKET)
@@ -593,10 +607,11 @@ class JaxOperators(OperatorSet):
             return z, z, z
         indptr_d, indices_d, pos_d = self._csr_dev(csr)
         total0, approx0 = self._jaxops.csr_expand_total(indptr_d, rows)
-        total = int(total0)                          # control-plane sync
-        if float(approx0) > _I32_MAX - 256:          # int32 sum wrapped
+        total, approx = self._sync("expand", (total0, approx0))
+        total = int(total)
+        if float(approx) > _I32_MAX - 256:           # int32 sum wrapped
             raise RuntimeError(f"intermediate blow-up: expansion would "
-                               f"produce ~{float(approx0):.3g} rows "
+                               f"produce ~{float(approx):.3g} rows "
                                f"(beyond the int32 staging envelope)")
         if max_out is not None and total > max_out:
             raise RuntimeError(f"intermediate blow-up: expansion would "
@@ -622,7 +637,7 @@ class JaxOperators(OperatorSet):
         founds, fposs = [], []
         for s in range(0, R, _SLAB_ROWS):
             e = min(s + _SLAB_ROWS, R)
-            d_hi = int(deg[s:e].max())               # control-plane sync
+            d_hi = int(self._sync("intersect", deg[s:e].max()))
             if d_hi == 0:
                 founds.append(jnp.zeros(e - s, bool))
                 fposs.append(jnp.zeros(e - s, jnp.int32))
@@ -702,10 +717,11 @@ class JaxOperators(OperatorSet):
             self._jaxops.sortmerge_bounds_padded(
                 self._pad(lk, Lp, _I32_MAX), self._pad(rk, Rp, _I32_MAX),
                 L, R)
-        total = int(total0)                         # control-plane sync
-        if float(approx0) > _I32_MAX - 256:         # int32 sum wrapped
+        total, approx = self._sync("join", (total0, approx0))
+        total = int(total)
+        if float(approx) > _I32_MAX - 256:          # int32 sum wrapped
             raise RuntimeError(f"intermediate blow-up: join would produce "
-                               f"~{float(approx0):.3g} rows (beyond the "
+                               f"~{float(approx):.3g} rows (beyond the "
                                f"int32 staging envelope)")
         if max_out is not None and total > max_out:
             raise RuntimeError(f"intermediate blow-up: join would produce "
@@ -756,7 +772,7 @@ class JaxOperators(OperatorSet):
         keys_p = self._pad(keys, np2)
         order, _vstart, flag_order, ng0 = \
             self._jaxops.group_boundaries_padded(keys_p, n)
-        ng = int(ng0)                                # control-plane sync
+        ng = int(self._sync("group", ng0))
         starts = flag_order[:ng]                     # ascending run starts
         gp = _pow2(ng, _TAIL_MIN_BUCKET)
         names = list(values)

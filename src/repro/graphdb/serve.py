@@ -174,7 +174,6 @@ class ServeStats:
         # per-wave compile-event counts from the (wave-scoped) KernelStats
         # window — a warmed server holds these flat at zero
         self.wave_compiles: list[int] = []
-        self.wave_chain_compiles: list[int] = []
         # KernelStats totals over every wave ({"kind:label": n})
         self.kernels: dict[str, int] = {}
         self.per_plan: dict = {}               # cache_key -> summary dict
@@ -195,7 +194,6 @@ class ServeStats:
         compiles = sum(v for k, v in kernels.items()
                        if k.startswith("compile:"))
         self.wave_compiles.append(compiles)
-        self.wave_chain_compiles.append(kernels.get("compile:fused_chain", 0))
         for k, n in kernels.items():
             self.kernels[k] = self.kernels.get(k, 0) + n
         plan = self._plan(key)
@@ -240,7 +238,8 @@ class ServeStats:
             "latency_p50_ms": _percentile(self.latency_s, 50) * 1e3,
             "latency_p99_ms": _percentile(self.latency_s, 99) * 1e3,
             "fallbacks": dict(self.fallbacks),
-            "compiles_per_wave": list(self.wave_compiles),
+            "compiles": sum(self.wave_compiles),
+            "waves_with_compiles": sum(1 for c in self.wave_compiles if c),
             "kernels": dict(self.kernels),
             "failed": self.failed,
             "cancelled": self.cancelled,
@@ -289,7 +288,8 @@ class ServeStats:
             f"  latency p50={s['latency_p50_ms']:.2f}ms "
             f"p99={s['latency_p99_ms']:.2f}ms",
             f"  fallbacks={s['fallbacks'] or '{}'} "
-            f"compiles/wave={s['compiles_per_wave']}",
+            f"compiles={s['compiles']} in {s['waves_with_compiles']} "
+            f"waves",
             f"  containment: failed={s['failed']} retries={s['retries']} "
             f"bisections={s['bisections']} quarantined={s['quarantined']} "
             f"cancelled={s['cancelled']} deadline_aborts="
@@ -526,33 +526,36 @@ class QueryServer:
             return
         pq = reqs[0].prepared
         ops = pq.spec.operators(self.gopt.store)
-        # wave-scoped ledgers: no bleed across waves, bounded growth
-        ops.reset_ledgers()
-        start = time.perf_counter()
-        for r in reqs:
-            r.start_s = start
-        self.stats.deduped += \
-            len(reqs) - len({_freeze(r.params or {}) for r in reqs})
-        exec_kw = dict(self.exec_kw)
-        if reqs[0].snapshot is not None:
-            # the wave is snapshot-homogeneous by formation; execute the
-            # whole batch against the wave's pinned snapshot
-            exec_kw["snapshot"] = reqs[0].snapshot
-        self._samples[key] = reqs[0].params
-        if not self.containment:
-            # uncontained (legacy) path: one failure kills the whole wave
-            # and escapes to the caller — the perf baseline
-            self._exec_group(pq, reqs, exec_kw, 0)
-        else:
-            level, probe = self._breaker_pick(key)
-            outcome = {"level_failures": 0, "escalated_to": None}
-            self._contained_exec(key, pq, reqs, exec_kw, level,
-                                 self.max_retries, outcome)
-            self._breaker_report(key, level, probe, outcome)
-        self.stats.record_wave(key, reqs, _pow2(len(reqs)),
-                               time.perf_counter() - start,
-                               ops.kernel_stats.summary())
-        self._update_hotness(key, len(reqs))
+        # one span per wave; a request's spans share its wave's number
+        with ops.span("gopt.wave", wave=self.stats.waves, n=len(reqs),
+                      rids=",".join(str(r.rid) for r in reqs)):
+            # wave-scoped ledgers: no bleed across waves, bounded growth
+            ops.reset_ledgers()
+            start = time.perf_counter()
+            for r in reqs:
+                r.start_s = start
+            self.stats.deduped += \
+                len(reqs) - len({_freeze(r.params or {}) for r in reqs})
+            exec_kw = dict(self.exec_kw)
+            if reqs[0].snapshot is not None:
+                # the wave is snapshot-homogeneous by formation; execute
+                # the whole batch against the wave's pinned snapshot
+                exec_kw["snapshot"] = reqs[0].snapshot
+            self._samples[key] = reqs[0].params
+            if not self.containment:
+                # uncontained (legacy) path: one failure kills the whole
+                # wave and escapes to the caller — the perf baseline
+                self._exec_group(pq, reqs, exec_kw, 0)
+            else:
+                level, probe = self._breaker_pick(key)
+                outcome = {"level_failures": 0, "escalated_to": None}
+                self._contained_exec(key, pq, reqs, exec_kw, level,
+                                     self.max_retries, outcome)
+                self._breaker_report(key, level, probe, outcome)
+            self.stats.record_wave(key, reqs, _pow2(len(reqs)),
+                                   time.perf_counter() - start,
+                                   ops.kernel_stats.summary())
+            self._update_hotness(key, len(reqs))
 
     def _level_kw(self, exec_kw: dict, level: int) -> dict:
         """Execution kwargs for one degradation-ladder rung: 0 = native

@@ -192,10 +192,11 @@ class ShardedOperators(JaxOperators):
             rows_p, ip_d)
         self.kernel_stats.record("dispatch", "sharded_deg")
         self._record_exchange("psum", "expand_frontier", fcap)
-        total = int(t0)                          # control-plane sync
-        if float(tf0) > 2147483391.0:            # int32 sum wrapped
+        total, approx = self._sync("sharded_expand", (t0, tf0))
+        total = int(total)
+        if float(approx) > 2147483391.0:         # int32 sum wrapped
             raise RuntimeError(f"intermediate blow-up: expansion would "
-                               f"produce ~{float(tf0):.3g} rows (beyond "
+                               f"produce ~{float(approx):.3g} rows (beyond "
                                f"the int32 staging envelope)")
         if max_out is not None and total > max_out:
             raise RuntimeError(f"intermediate blow-up: expansion would "
@@ -366,7 +367,7 @@ class ShardedOperators(JaxOperators):
         self.kernel_stats.record("dispatch", "group")
         order, vstart, _flag_order, ng0 = \
             self._jaxops.group_boundaries_padded(self._pad(keys_g, np2), n)
-        ng = int(ng0)                                # control-plane sync
+        ng = int(self._sync("sharded_group", ng0))
         # ascending-rank group id per original row: cumsum over the sorted
         # domain carried back through the inverse permutation
         gid_sorted = jnp.cumsum(vstart.astype(jnp.int32)) - 1

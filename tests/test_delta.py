@@ -618,12 +618,14 @@ def test_serve_compaction_repins_chains():
     pre, _ = _run(copy.deepcopy(ms), Q2HOP, "numpy")
     ev = srv.compact()
     assert ev["repinned_plans"] >= 1
-    n_waves = len(srv.stats.wave_chain_compiles)
+    n_waves = srv.stats.waves
+    chain = srv.stats.kernels.get("compile:fused_chain", 0)
     r = srv.submit(Q2HOP)
     srv.drain()
     assert _rows(r.table) == pre
-    post_compiles = srv.stats.wave_chain_compiles[n_waves:]
-    assert post_compiles and all(c == 0 for c in post_compiles), post_compiles
+    assert srv.stats.waves > n_waves
+    assert srv.stats.kernels.get("compile:fused_chain", 0) == chain, \
+        srv.stats.kernels
     srv.close()
 
 

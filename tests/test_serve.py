@@ -227,10 +227,14 @@ def test_warm_server_compiles_stay_flat(serve_gopt):
     for pid in range(32):
         srv.submit(CHAIN, {"pid": pid})
     done = srv.drain()
+    chain = srv.stats.kernels.get("compile:fused_chain", 0)
+    for pid in range(32, 40):
+        srv.submit(CHAIN, {"pid": pid})
+    done += srv.drain()
     srv.close()
-    assert len(done) == 32 and sum(srv.stats.wave_sizes) == 32
+    assert len(done) == 40 and sum(srv.stats.wave_sizes) == 40
     assert srv.stats.wave_compiles[-1] == 0, srv.stats.wave_compiles
-    assert srv.stats.wave_chain_compiles[-1] == 0
+    assert srv.stats.kernels.get("compile:fused_chain", 0) == chain
 
 
 # ----------------------------------------------------------- EXPLAIN surface

@@ -432,3 +432,26 @@ def test_lint_contracts_repo_clean():
          "--strict"], capture_output=True, text=True)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "0 violation(s)" in out.stdout
+
+
+def test_lint_contracts_flags_a_bare_sync():
+    import ast
+    import importlib.util
+    import pathlib
+    path = (pathlib.Path(__file__).resolve().parent.parent / "tools"
+            / "lint_contracts.py")
+    spec = importlib.util.spec_from_file_location("lint_contracts", path)
+    lint = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(lint)
+    src = (
+        "class JaxOperators:\n"
+        "    def expand(self, csr, rows):\n"
+        "        total0, _ = self._jaxops.csr_expand_total(csr, rows)\n"
+        "        total = int(total0)\n"
+        "        cnt = int(self._sync('nonzero', rows.sum()))\n"
+        "        hi = int(rows.max())\n"
+        "        return total, cnt, hi\n")
+    hits = lint.sync_violations(ast.parse(src), "jax_backend.py")
+    # the bare scalar read and the bare reduction; not the _sync'd one
+    assert [line for line, _ in hits] == [4, 6], hits
+    assert all(msg.startswith("R2 device->host sync") for _, msg in hits)

@@ -15,7 +15,13 @@ R2  ledger discipline — any function in a compiled backend that calls
     ``jit(`` must record on ``kernel_stats`` (compiles must be visible in
     PROFILE), and the named transfer entry points (``asarray``,
     ``_array_to_host``, ``_upload``, ``to_host``) must record on
-    ``transfer_stats``.
+    ``transfer_stats``.  Every device->host scalar sync goes through
+    ``_sync`` (which records ``sync:<label>`` and opens its span): a line
+    marked ``# control-plane sync`` must call it, and ``int(``/``float(``/
+    ``bool(``/``np.asarray(`` may not take a device value the rule can
+    see — a reduction (``.sum()``, ``.max()``, ...) of anything but
+    ``_sync``'s host result, or a name bound from a device call (a
+    ``jnp``/``jaxops`` function or a compiled program) — anywhere else.
 
 R3  lock discipline — in ``graphdb/serve.py``, every admission-side call
     (``self.gopt.prepare(``, ``self.gopt.touch_plan(``) must sit lexically
@@ -85,6 +91,17 @@ R2_ALLOW = frozenset({
     # _smap only builds the jitted callable; its callers go through _prog,
     # which records compile:<kind> on first build of each keyed program
     "sharded_backend.py:ShardedOperators._smap",
+})
+SYNC_MARK = "# control-plane sync"
+HOST_CASTS = frozenset({"int", "float", "bool"})
+REDUCTIONS = frozenset({"sum", "max", "min", "any", "all", "item"})
+# device value roots: calls through these produce device arrays
+DEVICE_ROOTS = frozenset({"jnp", "_jnp", "_jaxops", "jaxops", "lax", "_lax"})
+# the names the backends bind their compiled programs to before calling
+PROGRAM_NAMES = frozenset({"fn", "prog"})
+# functions whose reductions read host arrays only
+SYNC_ALLOW = frozenset({
+    "jax_backend.py:JaxOperators._csr_max_degree",  # host CSR indptr
 })
 
 # ------------------------------------------------------------------ R3 config
@@ -212,6 +229,98 @@ def check_ledgers(violations: list):
                      f"records on transfer_stats"))
 
 
+def _attr_root(node: ast.AST) -> str | None:
+    while isinstance(node, (ast.Attribute, ast.Subscript, ast.Call)):
+        node = node.func if isinstance(node, ast.Call) else node.value
+        if isinstance(node, ast.Attribute) and node.attr in DEVICE_ROOTS:
+            return node.attr
+    return node.id if isinstance(node, ast.Name) else None
+
+
+def _device_call(call: ast.Call) -> bool:
+    """A call that returns device values: a ``jnp``/``jaxops``/``lax``
+    function, a compiled program (``fn(...)``, ``prog(...)``), or a
+    program built and called at once (``self._prog(...)(...)``)."""
+    f = call.func
+    if isinstance(f, ast.Call):
+        return True
+    if isinstance(f, ast.Name):
+        return f.id in PROGRAM_NAMES
+    return _attr_root(f) in DEVICE_ROOTS
+
+
+def _targets(node: ast.AST) -> list:
+    if isinstance(node, ast.Name):
+        return [node.id]
+    if isinstance(node, (ast.Tuple, ast.List)):
+        return [n for e in node.elts for n in _targets(e)]
+    return []
+
+
+def sync_violations(tree: ast.AST, fname: str) -> list:
+    """``(line, message)`` for every device->host scalar conversion the
+    rule can see outside ``_sync`` in one compiled-backend module."""
+    out = []
+    for stack, scope in _iter_funcs(tree):
+        if not isinstance(scope, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            continue
+        qual = f"{fname}:{_qualname(stack)}"
+        if qual in SYNC_ALLOW:
+            continue
+        nodes = sorted((n for n in _own_statements(scope)
+                        if isinstance(n, (ast.Assign, ast.Call))),
+                       key=lambda n: (n.lineno, n.col_offset))
+        device, host = set(), set()
+        for n in nodes:
+            if isinstance(n, ast.Assign):
+                names = {t for tg in n.targets for t in _targets(tg)}
+                v = n.value
+                if not isinstance(v, ast.Call):
+                    continue
+                if isinstance(v.func, ast.Attribute) \
+                        and v.func.attr == "_sync":
+                    host |= names
+                    device -= names
+                elif _device_call(v):
+                    device |= names
+                    host -= names
+                continue
+            f = n.func
+            cast = (isinstance(f, ast.Name) and f.id in HOST_CASTS) or (
+                isinstance(f, ast.Attribute) and isinstance(f.value, ast.Name)
+                and f.value.id == "np" and f.attr in ("asarray", "array"))
+            if not cast or not n.args:
+                continue
+            arg = n.args[0]
+            hit = None
+            if _attr_root(arg) in device:
+                hit = "a device value"
+            elif (isinstance(arg, ast.Call)
+                  and isinstance(arg.func, ast.Attribute)
+                  and arg.func.attr in REDUCTIONS
+                  and _attr_root(arg.func.value) not in host):
+                hit = f"a .{arg.func.attr}() reduction"
+            if hit:
+                out.append((n.lineno,
+                            f"R2 device->host sync ({hit}) in "
+                            f"{_qualname(stack)!r} outside _sync (the "
+                            f"sync:<label> count and its span miss it)"))
+    return out
+
+
+def check_syncs(violations: list):
+    for rel in COMPILED_BACKENDS:
+        path = SRC / rel
+        text = path.read_text()
+        for i, line in enumerate(text.splitlines(), 1):
+            if SYNC_MARK in line and "_sync(" not in line:
+                violations.append((rel, i, f"R2 line marked "
+                                           f"{SYNC_MARK!r} does not call "
+                                           f"_sync"))
+        for line, msg in sync_violations(ast.parse(text), path.name):
+            violations.append((rel, line, msg))
+
+
 # --------------------------------------------------------------------------
 # R3: lock discipline in graphdb/serve.py
 # --------------------------------------------------------------------------
@@ -310,6 +419,7 @@ def main(argv=None) -> int:
     violations: list[tuple[str, int, str]] = []
     check_host_arrays(violations)
     check_ledgers(violations)
+    check_syncs(violations)
     check_serve_locks(violations)
     check_containment(violations)
 
