@@ -54,8 +54,8 @@ REQUIRED_OPERATORS = ("scan", "expand", "intersect", "join",
 # its own array type overrides all of them (plus vertex_prop/edge_prop) so
 # binding-table columns never leave the device between plan steps
 ARRAY_PRIMITIVES = ("asarray", "to_host", "take", "mask", "concat", "nonzero",
-                    "full", "arange", "isin", "searchsorted", "lexsort",
-                    "distinct_indices", "where")
+                    "nonzero_segments", "full", "arange", "isin",
+                    "searchsorted", "lexsort", "distinct_indices", "where")
 
 @dataclasses.dataclass(frozen=True)
 class CostParams:
@@ -341,6 +341,15 @@ class OperatorSet:
 
     def nonzero(self, m):
         return np.nonzero(m)[0]
+
+    def nonzero_segments(self, m):
+        """Compaction of a (k, n) bool mask, one segment per row: ``(seg, row,
+        counts)`` with ``seg``/``row`` the True positions in row-major
+        order (segment-major, ascending ``row`` within a segment) and
+        ``counts`` each segment's True count as a host array — the one
+        round trip a batched re-filter needs."""
+        seg, row = np.nonzero(m)
+        return seg, row, np.count_nonzero(m, axis=1)
 
     def full(self, n: int, value):
         return np.full(n, value)
@@ -637,9 +646,9 @@ def dtype_contract_failures(ops: OperatorSet) -> list[str]:
     Every backend: ``isin`` and ``intersect.found`` emit a real bool mask
     (callers compose masks with ``~``/``&``; bitwise-not on an int 0/1
     column corrupts silently — the PR-8 regression), and id/position
-    columns out of ``scan``/``arange``/``expand``/``intersect``/``nonzero``
-    are integer-kind.  Compiled (device) backends additionally pin those
-    columns to the int32 staging envelope."""
+    columns out of ``scan``/``arange``/``expand``/``intersect``/``nonzero``/
+    ``nonzero_segments`` are integer-kind.  Compiled (device) backends
+    additionally pin those columns to the int32 staging envelope."""
     fails: list[str] = []
     compiled = bool(getattr(ops, "compiled", False))
 
@@ -666,6 +675,10 @@ def dtype_contract_failures(ops: OperatorSet) -> list[str]:
         want_int("arange", ops.arange(4))
         want_int("nonzero",
                  ops.nonzero(A(np.array([False, True, True]))))
+        seg, row, _ = ops.nonzero_segments(A(np.array([[False, True],
+                                                       [True, True]])))
+        want_int("nonzero_segments.seg", seg)
+        want_int("nonzero_segments.row", row)
         csr = _conf_csr()
         # device backends cache uploaded CSR twins by id(csr): keep the
         # fixture alive on the ops instance so its id is never recycled by
@@ -722,6 +735,12 @@ def run_operator_conformance(ops: OperatorSet) -> list[str]:
               [5, 1, 3, 1, 0, 9])
         check("nonzero", ops.nonzero(A(np.array([False, True, False, True]))),
               [1, 3])
+        seg, row, cnt = ops.nonzero_segments(A(np.array(
+            [[False, True, True], [False, False, False],
+             [True, False, True]])))
+        check("nonzero_segments.seg", seg, [0, 0, 2, 2])
+        check("nonzero_segments.row", row, [1, 2, 0, 2])
+        check("nonzero_segments.counts", cnt, [2, 0, 2])
         check("full", ops.full(3, 7), [7, 7, 7])
         check("arange", ops.arange(4), [0, 1, 2, 3])
         check("isin", ops.isin(ids, [1, 5]),

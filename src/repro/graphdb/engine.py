@@ -36,9 +36,10 @@ Modes (used by the RBO ablation benchmarks):
 ``run_batch`` executes one plan for many parameter bindings in a single
 pattern pass: parameter-dependent predicates are relaxed to the union of
 the per-binding masks during the pattern phase (a multi-binding scan
-filter), then re-applied exactly per binding before each binding's
-relational tail — row-identical to looping ``run`` per binding, but the
-expansion/join work is shared.
+filter), then re-applied exactly per binding before the relational tail
+— row-identical to looping ``run`` per binding, but the expansion/join
+work is shared.  Both steps evaluate a predicate for all bindings at once
+as one (bindings x rows) mask (``_eval_batch``).
 """
 from __future__ import annotations
 
@@ -550,16 +551,7 @@ class Engine:
         return tbl
 
     def _union_mask(self, tbl: Table, pred):
-        saved = self._params
-        m = None
-        try:
-            for b in self._batch:
-                self._params = b
-                mb = self._eval(tbl, pred).astype(bool)
-                m = mb if m is None else (m | mb)
-        finally:
-            self._params = saved
-        return m
+        return self._eval_batch(tbl, pred, self._batch).any(axis=0)
 
     def exec_pattern(self, pattern: Pattern, node: PlanNode,
                      stats: ExecStats) -> Table:
@@ -834,6 +826,72 @@ class Engine:
             return acc
         raise TypeError(f"cannot evaluate {e!r}")
 
+    def _eval_batch(self, tbl: Table, pred, bound: list[dict]):
+        """A parameter predicate under every binding at once: the (k, n)
+        bool matrix whose row i is ``_eval(tbl, pred)`` under ``bound[i]``.
+        ``expr <op> $param`` evaluates ``expr`` once and broadcasts it
+        against a (k, 1) parameter column; any other shape (``$param <op>
+        expr``, ``IN $set``, a binding value with no common numeric dtype)
+        stacks the k per-binding rows."""
+        m = self._eval_k(tbl, pred, bound)
+        if m is not None:
+            return m
+        saved = self._params
+        rows = []
+        try:
+            for b in bound:
+                self._params = b
+                rows.append(self._eval(tbl, pred).astype(bool))
+        finally:
+            self._params = saved
+        return self.ops.concat(rows).reshape(len(bound), tbl.nrows)
+
+    def _eval_k(self, tbl: Table, e, bound: list[dict]):
+        """``e`` for every binding, broadcastable to (k, n): ``_eval``'s
+        (n,) column where ``e`` is parameter-free, else (k, n); None
+        where only the per-binding ``_eval`` can say."""
+        if not ir.expr_params(e):
+            return self._eval(tbl, e)
+        if isinstance(e, ir.Cmp):
+            lhs, rhs = e.lhs, e.rhs
+            if isinstance(rhs, ir.Param) and not ir.expr_params(lhs):
+                r = self._param_column(
+                    [self._encode_scalar(lhs, b[rhs.name]) for b in bound])
+                if r is None:
+                    return None
+                return _CMP[e.op](self._eval(tbl, lhs), r)
+            return None
+        if isinstance(e, ir.BoolOp):
+            parts = []
+            for a in e.args:
+                p = self._eval_k(tbl, a, bound)
+                if p is None:
+                    return None
+                parts.append(p.astype(bool))
+            if e.op == "NOT":
+                return ~parts[0]
+            acc = parts[0]
+            for p in parts[1:]:
+                acc = (acc & p) if e.op == "AND" else (acc | p)
+            return acc
+        return None
+
+    def _param_column(self, values: list):
+        """One parameter's k binding values as a (k, 1) backend column, or
+        None where they share no numeric dtype or the backend's column
+        would not hold them exactly (the per-binding path then decides,
+        and fails as it would)."""
+        kinds = {np.asarray(v).dtype.kind for v in values}
+        if len(kinds) != 1 or not kinds <= {"i", "f"}:
+            return None
+        a = np.asarray(values)[:, None]
+        col = self.ops.asarray(a)
+        dt = np.dtype(col.dtype)
+        if dt.kind == "i" and (a.min() < np.iinfo(dt).min
+                               or a.max() > np.iinfo(dt).max):
+            return None
+        return col
+
     def _encode_scalar(self, lhs, value):
         if isinstance(value, str):
             if isinstance(lhs, ir.Prop):
@@ -1045,6 +1103,26 @@ class Engine:
             m = mp if m is None else (m & mp)
         return tbl.mask(m)
 
+    def _refilter_stacked(self, tbl: Table, deferred, bound: list[dict]):
+        """``_refilter`` for every binding in one pass: the deferred
+        predicates' (k, n) mask compacts once into the segment-major stack
+        of each binding's rows (original row order within a binding) with
+        a ``__seg`` binding-id column.  Returns ``(stack or None when every
+        binding is empty, host row count per binding)``."""
+        k, n = len(bound), tbl.nrows
+        if n == 0:
+            return None, [0] * k
+        m = None
+        for p in deferred:
+            mp = self._eval_batch(tbl, p, bound)
+            m = mp if m is None else (m & mp)
+        if m is None:        # no deferred predicate: every binding keeps all
+            m = self.ops.full(k * n, True).reshape(k, n)
+        seg, row, counts = self.ops.nonzero_segments(m)
+        if not counts.any():
+            return None, counts
+        return tbl.take(row).with_cols({"__seg": seg}), counts
+
     def _run_tails_loop(self, ops, tbl, bound, deferred, shared, pattern_s,
                         pattern_transfers, pattern_kernels,
                         pattern_exchanges, reason=None):
@@ -1117,21 +1195,15 @@ class Engine:
         seg = None       # all bindings empty: nothing to stack
         with self.ops.phase("tail"):
             with _Op(self, "BATCH_BIND", st) as bind:
-                parts, counts = [], []
-                for i, b in enumerate(bound):
-                    t = self._refilter(tbl, deferred, b)
-                    counts.append(t.nrows)
-                    if t.nrows:
-                        parts.append(t.with_cols(
-                            {"__seg": self.ops.full(t.nrows, i)}))
-                stacked = Table.concat(parts) if parts else None
+                stacked, counts = self._refilter_stacked(tbl, deferred,
+                                                         bound)
                 bind.log("BATCH_BIND", stacked)
-            if parts:
+            if stacked is not None:
                 self._params = {}
                 for op in ops[1:]:
                     stacked = self._run_relational_seg(stacked, op,
                                                        len(bound), st)
-        if parts:
+        if stacked is not None:
             with self.ops.phase("deliver"):
                 host = self.ops.to_host(stacked)
             seg = np.asarray(host.cols.pop("__seg"))

@@ -421,25 +421,44 @@ class JaxOperators(OperatorSet):
         return self._jnp.concatenate([self._jnp.asarray(p) for p in parts])
 
     def nonzero(self, m):
-        # argsort-shaped flatnonzero: jnp.nonzero's eager path rides heavy
-        # python machinery per call.  A stable sort puts True positions
-        # first in original order; the count sync sizes the slice.  The
-        # mask pads to a pow2 capacity bucket (pads False, so they sort
-        # last among the dropped rows) — mask/compaction sites key compiles
-        # on the bucket, not the exact table length.
         jnp = self._jnp
         m = jnp.asarray(m)
         if m.dtype != bool:
             m = m != 0          # int 0/1 masks: sum/argsort need real bools
-        n = m.shape[0]
         cnt = int(self._sync("nonzero", m.sum()))
         if cnt == 0:
             return jnp.zeros(0, jnp.int32)
-        np2 = _pow2(n, _TAIL_MIN_BUCKET)
+        return self._true_positions(m, cnt)
+
+    def nonzero_segments(self, m):
+        # one sync fetches the k per-segment counts (their sum sizes the
+        # slice); the flattened mask's positions split into (segment, row)
+        # on device
+        jnp = self._jnp
+        m = jnp.asarray(m)
+        if m.dtype != bool:
+            m = m != 0          # int 0/1 masks: the argsort needs real bools
+        counts = self._sync("refilter", m.sum(axis=1))
+        total = int(counts.sum())
+        if total == 0:
+            empty = jnp.zeros(0, jnp.int32)
+            return empty, empty, counts
+        flat = self._true_positions(m.reshape(-1), total)
+        n = m.shape[1]
+        return flat // n, flat % n, counts
+
+    def _true_positions(self, m, cnt: int):
+        # argsort-shaped flatnonzero of a flat bool mask holding ``cnt``
+        # Trues: jnp.nonzero's eager path rides heavy python machinery per
+        # call.  A stable sort puts True positions first in original
+        # order.  The mask pads to a pow2 capacity bucket (pads False, so
+        # they sort last among the dropped rows) — mask/compaction sites
+        # key compiles on the bucket, not the exact table length.
+        np2 = _pow2(m.shape[0], _TAIL_MIN_BUCKET)
         self._tail_compile("nonzero", (np2,))
         self.kernel_stats.record("dispatch", "nonzero")
-        order = jnp.argsort(~self._pad(m, np2, False))   # stable
-        return order[:cnt].astype(jnp.int32)
+        order = self._jnp.argsort(~self._pad(m, np2, False))   # stable
+        return order[:cnt].astype(self._jnp.int32)
 
     def full(self, n: int, value):
         return self._jnp.full(n, value)

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from benchmarks import queries as Q
+from repro.core import ir
 from repro.core.physical import ExpandChainNode, plan_operators
 from repro.core.physical_spec import get_spec
 from repro.graphdb.chain import build_chain_spec
@@ -270,6 +271,112 @@ def test_execute_many_stacked_empty_binding(gopt_small):
     batched = pq.execute_many(bindings)
     for (lt, _), (bt, _) in zip(loop, batched):
         _table_eq(lt, bt)
+
+
+# ----------------------------------------- one-pass re-filter of a batch
+
+_KNOWS = "MATCH (p:PERSON)-[:KNOWS]->(f:PERSON) WHERE "
+_NONE = 10**9        # an id no vertex has
+# (query, distinct bindings); the server pads a wave to a power of two
+# with copies of its first binding, and so does the test
+_REFILTER_CASES = {
+    "eq_k2": (_KNOWS + "p.id = $pid RETURN p.id, f.id",
+              [{"pid": p} for p in (3, 5)]),
+    "eq_k3": (_KNOWS + "p.id = $pid RETURN p.id, f.id",
+              [{"pid": p} for p in (3, 5, 9)]),
+    "eq_k4": (_KNOWS + "p.id = $pid RETURN p.id, f.id",
+              [{"pid": p} for p in (3, 5, 9, 12)]),
+    "eq_k8": (_KNOWS + "p.id = $pid RETURN p.id, f.id",
+              [{"pid": p} for p in (3, 5, 9, 12, 17, 20, 25, 31)]),
+    "param_lhs": (_KNOWS + "$pid = p.id RETURN p.id, f.id",
+                  [{"pid": p} for p in (5, 3, 9)]),
+    "lt": ("MATCH (p:PERSON) WHERE p.id < $x RETURN p.id",
+           [{"x": v} for v in (4, 9, 2)]),
+    "ne_and_lit": (_KNOWS + "p.id <> $pid AND f.id < 30 RETURN p.id, f.id",
+                   [{"pid": p} for p in (3, 5)]),
+    "or_lit": (_KNOWS + "p.id = $pid OR p.id = 7 RETURN p.id, f.id",
+               [{"pid": p} for p in (3, 5, 9)]),
+    "not_and_lit": (_KNOWS + "NOT p.id = $pid AND p.id < 12 "
+                    "RETURN p.id, f.id", [{"pid": p} for p in (3, 5)]),
+    "mixed_empty": (_KNOWS + "p.id = $pid RETURN p.id, f.id",
+                    [{"pid": 5}, {"pid": _NONE}, {"pid": 3}]),
+    "all_empty": (_KNOWS + "p.id = $pid RETURN p.id, f.id",
+                  [{"pid": _NONE}, {"pid": _NONE + 1}, {"pid": _NONE + 2}]),
+    # the unions of p.id and f.id keep rows that no single binding does
+    # (3 knows 10 but not 6, 5 knows 6 but not 10)
+    "all_empty_after_union": (
+        _KNOWS + "p.id = $a AND f.id = $b RETURN p.id, f.id",
+        [{"a": 3, "b": 6}, {"a": 5, "b": 10}]),
+    "mixed_empty_after_union": (
+        _KNOWS + "p.id = $a AND f.id = $b RETURN p.id, f.id",
+        [{"a": 3, "b": 6}, {"a": 5, "b": 10}, {"a": 3, "b": 10}]),
+    "string": ("MATCH (p:PERSON) WHERE p.firstName = $name RETURN p.id",
+               [{"name": n} for n in ("Jan", "Li", "Nobody")]),
+    "no_param": ("MATCH (p:PERSON)-[:KNOWS]->(f:PERSON) WHERE p.id < 9 "
+                 "RETURN p.id, f.id", [{}, {}]),
+    "in_set": (_KNOWS + "p.id IN $ids RETURN p.id, f.id",
+               [{"ids": [3, 5, 9]}, {"ids": [12, 17, 20]},
+                {"ids": [5, 25, 31]}]),
+}
+
+
+def _pow2_padded(bindings):
+    k = 1 << (len(bindings) - 1).bit_length()
+    return bindings + [bindings[0]] * (k - len(bindings))
+
+
+@pytest.mark.parametrize("case", sorted(_REFILTER_CASES))
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_execute_many_batched_refilter_parity(gopt_small, backend, case):
+    """The stacked tail's one-pass re-filter (one (bindings x rows) mask,
+    one compaction) is row-identical to executing each binding alone, for
+    broadcast predicates, stacked ones (``IN $set``, ``$param = expr``)
+    and empty bindings;
+    the jax backend fetches its per-binding counts in one round trip."""
+    text, distinct = _REFILTER_CASES[case]
+    bindings = _pow2_padded(distinct)
+    pq = gopt_small.prepare(text, backend=backend)
+    loop = pq.execute_many(bindings, batch=False)
+    batched = pq.execute_many(bindings)
+    assert len(batched) == len(loop) == len(bindings)
+    for i, ((lt, _), (bt, bstats)) in enumerate(zip(loop, batched)):
+        _table_eq(lt, bt, f"{case}[{i}]")
+        assert bstats.fallbacks == {}, bstats.fallbacks
+    if backend == "jax":
+        refilters = batched[0][1].kernels.get("sync:refilter", 0)
+        assert refilters == (0 if case == "all_empty" else 1)
+
+
+_UNION_PREDS = {
+    "eq": ir.Cmp("=", ir.Prop("p", "id"), ir.Param("pid")),
+    "or_lit": ir.BoolOp("OR", (ir.Cmp("<", ir.Prop("p", "id"),
+                                      ir.Param("pid")),
+                               ir.Cmp("=", ir.Prop("p", "id"), ir.Lit(40)))),
+    "in_set": ir.InSet(ir.Prop("p", "id"), ir.Param("ids")),
+}
+
+
+@pytest.mark.parametrize("pred", sorted(_UNION_PREDS))
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_union_mask_is_or_of_binding_masks(small_ldbc, backend, pred):
+    """The pattern phase's union filter is the OR over bindings of each
+    binding's own mask."""
+    from repro.graphdb.engine import Engine
+    eng = Engine(small_ldbc, backend=backend)
+    lo, hi = small_ldbc.type_range("PERSON")
+    tbl = eng._table({"p": eng.ops.scan(lo, hi)}, hi - lo)
+    bound = [{"pid": 3, "ids": [3, 9]}, {"pid": 17, "ids": [12]},
+             {"pid": 3, "ids": [3, 9]}, {"pid": _NONE, "ids": [_NONE]}]
+    e = _UNION_PREDS[pred]
+    eng._batch = bound
+    got = np.asarray(eng.ops.to_host(eng._union_mask(tbl, e)))
+    want = np.zeros(hi - lo, bool)
+    for b in bound:
+        eng._params = b
+        want |= np.asarray(eng.ops.to_host(eng._eval(tbl, e))).astype(bool)
+    assert got.dtype == np.bool_
+    np.testing.assert_array_equal(got, want)
+    assert got.any()
 
 
 # --------------------------------------------------- widened SUM/AVG on device

@@ -296,6 +296,49 @@ def test_distinct_bucket_plateau_and_semantics(small_ldbc):
     assert ks.summary(m).get("compile:distinct", 0) == 1
 
 
+def _segment_ops(kind, store):
+    from repro.graphdb.faults import FaultPlan, faulty_spec
+    from repro.graphdb.host_staging import HostStagingOperators
+    if kind == "sharded":
+        return _fresh_ops(store)
+    if kind == "host_staging":
+        return HostStagingOperators(get_spec("jax").make_operators(store))
+    if kind.startswith("faulty:"):
+        return faulty_spec(kind.split(":")[1], FaultPlan([])).operators(store)
+    return get_spec(kind).make_operators(store)
+
+
+@pytest.mark.parametrize("kind", ["numpy", "host_staging", "jax",
+                                  "faulty:numpy", "faulty:jax", "sharded"])
+def test_nonzero_segments_contract(small_ldbc, kind):
+    """The batched re-filter's compaction: segment-major positions,
+    ascending rows within a segment, host counts equal to the row sums,
+    empty outputs for an all-False mask, on every shipped operator set."""
+    ops = _segment_ops(kind, small_ldbc)
+    validate_operator_set(ops, conformance=True)
+    rng = np.random.default_rng(3)
+    for k, n in ((1, 5), (2, 7), (4, 3), (8, 33)):
+        m = rng.random((k, n)) < 0.4
+        m[k // 2] = False                   # an empty segment
+        seg, row, counts = ops.nonzero_segments(ops.asarray(m))
+        want_seg, want_row = np.nonzero(m)
+        np.testing.assert_array_equal(np.asarray(ops.to_host(seg)), want_seg)
+        np.testing.assert_array_equal(np.asarray(ops.to_host(row)), want_row)
+        assert isinstance(counts, np.ndarray)
+        np.testing.assert_array_equal(counts, m.sum(axis=1))
+    # an int 0/1 mask selects what its bool twin does (never bitwise-not)
+    mi = np.array([[0, 1, 1], [0, 0, 0], [1, 0, 1]], np.int32)
+    seg, row, counts = ops.nonzero_segments(ops.asarray(mi))
+    np.testing.assert_array_equal(np.asarray(ops.to_host(seg)), [0, 0, 2, 2])
+    np.testing.assert_array_equal(np.asarray(ops.to_host(row)), [1, 2, 0, 2])
+    np.testing.assert_array_equal(counts, [2, 0, 2])
+    seg, row, counts = ops.nonzero_segments(ops.asarray(np.zeros((4, 6),
+                                                                 bool)))
+    assert np.asarray(ops.to_host(seg)).shape == (0,)
+    assert np.asarray(ops.to_host(row)).shape == (0,)
+    np.testing.assert_array_equal(counts, [0, 0, 0, 0])
+
+
 def test_nonzero_pad_value_inert(small_ldbc):
     """Pad slots must never leak into the selected indices."""
     ops = get_spec("jax").make_operators(small_ldbc)

@@ -64,6 +64,30 @@ def test_every_sync_is_one_counted_round_trip(tiny_store, monkeypatch):
 
 
 
+IS4_SHAPED = "MATCH (n:PERSON) WHERE n.id = $pid RETURN n.id"
+
+
+def test_batched_refilter_is_one_sync(tiny_store, monkeypatch):
+    """A 4-binding wave re-filters in one round trip: one ``nonzero`` for
+    the union scan filter and one ``refilter``, under one BATCH_BIND."""
+    ops = get_spec("jax").operators(tiny_store)
+    names = []
+    real = ops.span
+    monkeypatch.setattr(ops, "span",
+                        lambda name, **a: names.append(name) or real(name,
+                                                                     **a))
+    pq = GOpt(tiny_store, backend="jax").prepare(IS4_SHAPED)
+    out = pq.execute_many([{"pid": p} for p in (3, 7, 11, 19)])
+    assert [t.nrows for t, _ in out] == [1, 1, 1, 1]
+    kernels = out[0][1].kernels
+    assert kernels.get("sync:refilter") == 1
+    assert kernels.get("sync:nonzero") == 1
+    assert names.count("gopt.op.BATCH_BIND") == 1
+    assert names.count("gopt.sync.refilter") == 1
+    _, stats = pq.execute({"pid": 3})
+    assert "sync:refilter" not in stats.kernels
+
+
 def _bind_rows(st):
     """PROFILE's rows of one batched binding, checked for shape: the same
     labels in ``op_times`` as in ``op_rows``, and exactly one BATCH_BIND."""
